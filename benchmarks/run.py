@@ -300,7 +300,7 @@ def bench_justinserve() -> None:
 
 
 def bench_kernels() -> None:
-    """Pallas kernels vs pure-jnp oracles (interpret mode, correctness +
+    """Pallas kernels vs their oracles (interpret mode, correctness +
     per-call wall time on this CPU host)."""
     import numpy as np
     import jax.numpy as jnp
@@ -308,25 +308,24 @@ def bench_kernels() -> None:
     reg = _registry()
 
     from repro.kernels.sorted_probe.ops import probe
-    table = jnp.asarray(np.unique(rng.integers(0, 1 << 20, 4096))
-                        .astype(np.int32))
-    queries = jnp.asarray(rng.integers(0, 1 << 20, 1024).astype(np.int32))
-    p1, f1 = probe(table, queries)
+    table = np.unique(rng.integers(0, 1 << 20, 4096))
+    queries = rng.integers(0, 1 << 20, 1024)
+    p1, f1 = probe(table, queries, impl="interpret")
     with reg.timer("kernel_sorted_probe") as tm:
-        p1, f1 = probe(table, queries)
+        p1, f1 = probe(table, queries, impl="interpret")
     p2, f2 = probe(table, queries, impl="ref")
     _row("kernel_sorted_probe", tm.us,
          f"match={bool((p1 == p2).all() and (f1 == f2).all())}")
 
     from repro.kernels.window_agg.ops import aggregate
-    seg = jnp.asarray(rng.integers(0, 512, 2048), jnp.int32)
-    vals = jnp.asarray(rng.normal(size=(2048, 4)), jnp.float32)
-    s1, c1 = aggregate(seg, vals, 512)
+    seg = rng.integers(0, 512, 2048).astype(np.int32)
+    vals = rng.normal(size=(2048, 4)).astype(np.float32)
+    s1, c1 = aggregate(seg, vals, 512, impl="interpret")
     with reg.timer("kernel_window_agg") as tm:
-        s1, c1 = aggregate(seg, vals, 512)
+        s1, c1 = aggregate(seg, vals, 512, impl="interpret")
     s2, c2 = aggregate(seg, vals, 512, impl="ref")
     _row("kernel_window_agg", tm.us,
-         f"allclose={bool(jnp.allclose(s1, s2, atol=1e-3))}")
+         f"allclose={bool(np.allclose(s1, s2, atol=1e-3))}")
 
     from repro.kernels.flash_attn.ops import attention
     q = jnp.asarray(rng.normal(size=(1, 2, 256, 64)), jnp.float32)

@@ -36,13 +36,12 @@ for policy in ("ds2", "justin"):
 print()
 
 print("=== 3. Pallas kernel vs oracle (sorted-run probe) ===")
-import jax.numpy as jnp                                   # noqa: E402
 from repro.kernels.sorted_probe.ops import probe          # noqa: E402
 
 rng = np.random.default_rng(0)
-table = jnp.asarray(np.unique(rng.integers(0, 1 << 20, 4096)).astype(np.int32))
-queries = jnp.asarray(rng.integers(0, 1 << 20, 512).astype(np.int32))
-p1, f1 = probe(table, queries)                 # Pallas (interpret on CPU)
-p2, f2 = probe(table, queries, impl="ref")     # jnp oracle
+table = np.unique(rng.integers(0, 1 << 20, 4096))
+queries = rng.integers(0, 1 << 20, 512)
+p1, f1 = probe(table, queries, impl="interpret")  # Pallas interpreter (CPU)
+p2, f2 = probe(table, queries, impl="ref")        # numpy oracle
 print(f"positions match: {bool((p1 == p2).all())}, "
       f"found match: {bool((f1 == f2).all())}")

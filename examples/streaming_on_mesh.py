@@ -3,8 +3,8 @@
 The control plane (Justin/DS2, placement) is host-side Python — like Flink's
 JobManager; this shows the DATA plane running on devices: keyed events are
 hash-partitioned over the mesh with shard_map and each shard aggregates its
-keys with the MXU-native window_agg kernel (one-hot matmul segment-sum, see
-src/repro/kernels/window_agg/).
+keys with the window_agg kernel (masked segment sums on the vector unit,
+see src/repro/kernels/window_agg/), here in the Pallas interpreter.
 
 Run:  PYTHONPATH=src python examples/streaming_on_mesh.py
 (uses 8 virtual CPU devices)
@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels.window_agg.ops import aggregate
+from repro.kernels.window_agg.kernel import window_agg
 
 N_TASKS = 8                      # operator parallelism = mesh size
 N_KEYS = 256                     # keyspace (per-task segment range)
@@ -32,9 +32,11 @@ vals = rng.normal(size=(N_TASKS, events_per_task, 4)).astype(np.float32)
 
 
 def task_fn(k, v):
-    """One task's window aggregation (runs per mesh shard)."""
-    sums, counts = aggregate(k[0], v[0], N_KEYS)
-    return sums[None], counts[None]
+    """One task's window aggregation (runs per mesh shard): the value
+    columns and a row of ones, whose sums are the per-key counts."""
+    rows = jnp.concatenate([v[0].T, jnp.ones((1, v.shape[1]), v.dtype)])
+    out = window_agg(k[0], rows, N_KEYS, interpret=True)     # [V + 1, keys]
+    return out[:-1].T[None], out[-1][None]
 
 
 agg = jax.jit(jax.shard_map(task_fn, mesh=mesh,

@@ -24,19 +24,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:                                   # jax >= 0.5 exports it at top level
-    _shard_map_impl = jax.shard_map
-    _SHMAP_CHECK_KW = "check_vma"
-except AttributeError:                 # jax 0.4.x: experimental, check_rep kw
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _SHMAP_CHECK_KW = "check_rep"
-
-
-def _shard_map(fn, *, mesh, in_specs, out_specs, check_vma):
-    return _shard_map_impl(fn, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs,
-                           **{_SHMAP_CHECK_KW: check_vma})
-
 from repro.models.config import ArchConfig
 from repro.models.layers import cdiv
 from repro.models.sharding import MeshCtx
@@ -132,8 +119,8 @@ def moe_ffn(params: dict, x: jax.Array, cfg: ArchConfig, mctx: MeshCtx
         y = lax.psum(y, tp)
         return y.reshape(x_loc.shape)
 
-    return _shard_map(local_fn, mesh=mctx.mesh, in_specs=tuple(in_specs),
-                      out_specs=P(dp, None, None), check_vma=False)(*args)
+    return jax.shard_map(local_fn, mesh=mctx.mesh, in_specs=tuple(in_specs),
+                         out_specs=P(dp, None, None), check_vma=False)(*args)
 
 
 def moe_ffn_2d(params: dict, x: jax.Array, cfg: ArchConfig, mctx: MeshCtx
@@ -204,8 +191,8 @@ def moe_ffn_2d(params: dict, x: jax.Array, cfg: ArchConfig, mctx: MeshCtx
         y = y.reshape(b, s, d)
         return lax.dynamic_slice(y, (row * b_loc, 0, 0), (b_loc, s, d))
 
-    return _shard_map(local_fn, mesh=mctx.mesh, in_specs=tuple(in_specs),
-                      out_specs=P(dp, None, None), check_vma=False)(*args)
+    return jax.shard_map(local_fn, mesh=mctx.mesh, in_specs=tuple(in_specs),
+                         out_specs=P(dp, None, None), check_vma=False)(*args)
 
 
 def moe_param_shapes(cfg: ArchConfig, n_layers: int) -> dict:

@@ -25,16 +25,19 @@ passes instead of per-put argsorts.
   in ``annihilated``).  Probes are batched sorted-run ranks — the
   ``sorted_probe`` kernel's job on TPU.
 
-Every kernel dispatch point has a numpy reference path that is the
-oracle for CPU-only CI; ``kernel_impl="pallas"`` routes probes and
-segment sums through ``repro.kernels`` (interpret mode off-TPU).  Weight
-sums on the pallas path ride the float32 MXU — exact below 2^24, far
-above any per-flush occurrence count.
+Every kernel dispatch point has a numpy path (``kernel_impl="numpy"``,
+the default and the oracle).  ``kernel_impl="pallas"`` routes probes and
+segment sums through the compiled ``repro.kernels`` on a TPU and refuses
+to run anywhere else; ``"interpret"`` runs the same kernels in the Pallas
+interpreter, which is how CPU tests cover them.  Keys reach the device as
+two int32 words each (lossless over int64).  Weight sums on the device
+are float32 — exact below 2^24, far above any per-flush occurrence
+count.
 
 Byte accounting uses the paper's *logical* entry size (1000 B values, as
 in the §3 microbenchmarks) while physical storage keeps ``value_words``
 int32 words per entry, so cache-capacity ratios match the paper exactly
-at 1/64th the RAM (see DESIGN.md §3 "hardware adaptation").
+at 1/64th the RAM.
 
 Decision-identity invariants (pinned by ``tests/test_engine_fastpath.py``,
 ``tests/test_lsm_differential.py`` against the frozen
@@ -63,7 +66,8 @@ CACHE_OVERHEAD = 2.5                 # block granularity + index/filter share
 MEMTABLE_RUNS = 8                    # delta runs absorbed before a
                                      # consolidation pass
 
-DEFAULT_KERNEL_IMPL = "numpy"        # "numpy" (oracle) | "pallas"
+KERNEL_IMPLS = ("numpy", "pallas", "interpret")   # oracle first
+DEFAULT_KERNEL_IMPL = "numpy"
 
 # CLOCK-scan lookup tables for the 8-way cache: ref bits of one set pack
 # into a byte, so "first zero way at/after the hand" and "unpack ref byte
@@ -74,11 +78,18 @@ _CLOCK_FIRST_ZERO = np.where(np.arange(256) == 255, 8,
                              np.argmin(_CLOCK_UNPACK, axis=1)).astype(np.int64)
 
 
+def _check_kernel_impl(name: str) -> None:
+    if name not in KERNEL_IMPLS:
+        raise ValueError(f"unknown kernel impl {name!r}")
+    if name == "pallas":
+        from repro.kernels.device import require_tpu
+        require_tpu()
+
+
 def set_kernel_impl(name: str) -> None:
     """Default probe/segment-sum backend for newly built stores."""
     global DEFAULT_KERNEL_IMPL
-    if name not in ("numpy", "pallas"):
-        raise ValueError(f"unknown kernel impl {name!r}")
+    _check_kernel_impl(name)
     DEFAULT_KERNEL_IMPL = name
 
 
@@ -219,8 +230,7 @@ class LSMStore:
         self.metrics = LSMMetrics()
         self.compact_filter = None                # optional keys->keep mask
         self.kernel_impl = kernel_impl or DEFAULT_KERNEL_IMPL
-        if self.kernel_impl not in ("numpy", "pallas"):
-            raise ValueError(f"unknown kernel impl {self.kernel_impl!r}")
+        _check_kernel_impl(self.kernel_impl)
         self.annihilated = 0          # weight dropped by compaction filters
         self._configure_memory(memory_mb)
         # sorted-unique (keys, weights, vals) runs, newest first
@@ -313,16 +323,11 @@ class LSMStore:
                    ) -> tuple[np.ndarray, np.ndarray]:
         """Batched sorted-run rank: (clipped position, hit mask).  Positions
         are only meaningful where ``hit`` — there they index the match."""
-        if self.kernel_impl == "pallas":
-            from jax.experimental import enable_x64
-
+        if self.kernel_impl != "numpy":
             from repro.kernels.sorted_probe.ops import probe
-            with enable_x64():       # int64 keys must not truncate to int32
-                pos, hit = probe(run_keys, queries, impl="pallas",
-                                 interpret=True)
-            pos = np.minimum(np.asarray(pos).astype(np.int64),
-                             max(len(run_keys) - 1, 0))
-            return pos, np.asarray(hit)
+            pos, hit = probe(run_keys, queries, impl=self.kernel_impl)
+            return np.minimum(pos.astype(np.int64),
+                              max(len(run_keys) - 1, 0)), hit
         pos = np.searchsorted(run_keys, queries)
         pos_c = np.minimum(pos, len(run_keys) - 1)
         hit = (run_keys[pos_c] == queries) & (pos < len(run_keys))
@@ -332,13 +337,12 @@ class LSMStore:
                      first_mask: np.ndarray) -> np.ndarray:
         """Per-unique-key weight sum over key-sorted deltas — the
         consolidation reduction (``window_agg`` kernel on TPU)."""
-        if self.kernel_impl == "pallas":
+        if self.kernel_impl != "numpy":
             from repro.kernels.window_agg.ops import aggregate
             gids = (np.cumsum(first_mask) - 1).astype(np.int32)
             sums, _ = aggregate(gids, sorted_w.astype(np.float32)[:, None],
-                                int(len(starts)), impl="pallas",
-                                interpret=True)
-            return np.asarray(sums)[:, 0].astype(np.int64)
+                                len(starts), impl=self.kernel_impl)
+            return sums[:, 0].astype(np.int64)
         return np.add.reduceat(sorted_w, starts)
 
     # ------------------------------------------------------------- write path
@@ -560,11 +564,11 @@ class LSMStore:
         # first run containing a key holds its newest payload.  One
         # source-major searchsorted covers every run at once (see
         # _mem_concat); the per-run loop remains as the fallback for the
-        # pallas kernel dispatch and out-of-range keys.  Both find the same
+        # device kernel dispatch and out-of-range keys.  Both find the same
         # key set with the same newest payload, so θ/τ charges agree.
         if self.mem_n:
             T = None
-            if self.kernel_impl != "pallas":
+            if self.kernel_impl == "numpy":
                 T, offs, srcs = self._mem_concat()
             fast = False
             if T is not None and len(T) and len(uq):

@@ -1,28 +1,36 @@
 """Edge-shape parity: sorted_probe / window_agg pallas kernels vs their
-numpy/jnp references, in interpret mode (no accelerator needed), plus the
-columnar LSM store's kernel dispatch (``kernel_impl="pallas"``) vs its
+numpy references, in interpret mode (no accelerator needed), plus the
+columnar LSM store's kernel dispatch (``kernel_impl="interpret"``) vs its
 numpy oracle path.
 
 The shape sweep here deliberately covers what tests/test_kernels.py's
 random sweeps don't pin: empty inputs, single-key tables, all-duplicate
 batches, and dtype-boundary keys (0, int_max — the kernel pads tables
-with int_max, which used to false-positive a genuine int_max probe).
+with the maximum key, which used to false-positive a genuine int_max
+probe), plus the int64 keys the store really holds: above 2^31 (q8's
+join keys reach ~2^38) and negative.
 """
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.sorted_probe.ops import probe
+from repro.kernels.device import bucket
+from repro.kernels.sorted_probe.kernel import (QUERY_BLOCK, TABLE_TILE,
+                                               sorted_probe)
+from repro.kernels.sorted_probe.ops import probe, split_keys
+from repro.kernels.window_agg.kernel import EVENT_TILE, SEG_BLOCK, window_agg
 from repro.kernels.window_agg.ops import aggregate
+from repro.state import lsm
 from repro.state.lsm import LSMStore
+
+INT64 = np.iinfo(np.int64)
 
 
 def assert_probe_parity(table, queries):
-    p1, f1 = probe(jnp.asarray(table), jnp.asarray(queries))
-    p2, f2 = probe(jnp.asarray(table), jnp.asarray(queries), impl="ref")
-    np.testing.assert_array_equal(np.asarray(p1), np.asarray(p2))
-    np.testing.assert_array_equal(np.asarray(f1), np.asarray(f2))
-    return np.asarray(p1), np.asarray(f1)
+    p1, f1 = probe(table, queries, impl="interpret")
+    p2, f2 = probe(table, queries, impl="ref")
+    np.testing.assert_array_equal(p1, p2)
+    np.testing.assert_array_equal(f1, f2)
+    return p1, f1
 
 
 # ------------------------------------------------------------- sorted_probe
@@ -65,36 +73,104 @@ def test_probe_duplicate_table_entries():
 @pytest.mark.parametrize("dtype", [np.int32, np.int64])
 def test_probe_dtype_boundaries(dtype):
     """0 and int_max as real keys AND as absent probes — the kernel pads
-    its table tiles with int_max, which must not read as a match.  int64
-    needs x64 enabled or jax silently truncates the arrays to int32."""
-    from jax.experimental import enable_x64
+    its table tiles with the int64 maximum, which must not read as a
+    match.  int64 keys cross to the device as two int32 words, split on
+    the host, so no x64 mode is involved."""
     hi = np.iinfo(dtype).max
-    with enable_x64():
-        table = np.array([0, 17, hi], dtype)
-        pos, found = assert_probe_parity(table, np.array([0, 1, hi, hi - 1],
-                                                         dtype))
-        np.testing.assert_array_equal(found, [True, False, True, False])
-        table_no_hi = np.array([0, 17], dtype)
-        _, found = assert_probe_parity(table_no_hi, np.array([hi], dtype))
-        assert not found.any()                  # padding must NOT match
+    table = np.array([0, 17, hi], dtype)
+    pos, found = assert_probe_parity(table, np.array([0, 1, hi, hi - 1],
+                                                     dtype))
+    np.testing.assert_array_equal(found, [True, False, True, False])
+    table_no_hi = np.array([0, 17], dtype)
+    _, found = assert_probe_parity(table_no_hi, np.array([hi], dtype))
+    assert not found.any()                      # padding must NOT match
+
+
+def test_probe_keys_above_2_31_at_q8_scale():
+    """q8 join keys are ((seller*4 + side) << 16) + window: ~2^38 at
+    600,000 sellers.  Keys that differ only in the high word, or only in
+    the low word's top bit, must rank exactly."""
+    rng = np.random.default_rng(2)
+    sellers = rng.choice(600_000, 3000, replace=False)
+    table = np.unique(((sellers * 4 + 1) << 16) + 1)
+    edges = np.array([(1 << 32) - 1, 1 << 32, (1 << 32) + (1 << 31),
+                      (1 << 38) + 5], np.int64)
+    table = np.unique(np.concatenate([table, edges]))
+    queries = np.concatenate([table[::3], table[::7] + 1, table[::5] - 1,
+                              edges ^ 1])
+    pos, found = assert_probe_parity(table, queries)
+    assert found[: len(table[::3])].all()
+    assert int(table.max()) > 2**37
+
+
+def test_probe_negative_keys():
+    table = np.array([INT64.min, -(1 << 40), -5, -1, 0, 3, 1 << 40],
+                     np.int64)
+    queries = np.array([INT64.min, INT64.min + 1, -(1 << 40) - 1, -5, -4,
+                        -1, 0, 2, 3, INT64.max], np.int64)
+    _, found = assert_probe_parity(table, queries)
+    np.testing.assert_array_equal(
+        found, [True, False, False, True, False, True, True, False, True,
+                False])
+
+
+def test_split_keys_preserves_int64_order():
+    """Signed lexicographic order of the (hi, lo) words == int64 order."""
+    rng = np.random.default_rng(8)
+    keys = np.concatenate([
+        rng.integers(INT64.min, INT64.max, 5000, dtype=np.int64),
+        np.array([INT64.min, INT64.max, -1, 0, 1, (1 << 31) - 1, 1 << 31,
+                  (1 << 32) - 1, 1 << 32, -(1 << 31), -(1 << 32)],
+                 np.int64)])
+    hi, lo = split_keys(keys)
+    assert hi.dtype == lo.dtype == np.int32
+    np.testing.assert_array_equal(np.lexsort((lo, hi)),
+                                  np.argsort(keys, kind="stable"))
+    back = (hi.astype(np.int64) << 32) \
+        | (lo.view(np.uint32) ^ np.uint32(1 << 31)).astype(np.int64)
+    np.testing.assert_array_equal(back, keys)
 
 
 def test_probe_exact_tile_multiple():
     """Table/query sizes exactly at the kernel tile sizes (no padding)."""
-    table = np.arange(2048, dtype=np.int64) * 3
-    queries = np.arange(512, dtype=np.int64) * 3 + 1   # all absent
+    table = np.arange(TABLE_TILE, dtype=np.int64) * 3
+    queries = np.arange(QUERY_BLOCK, dtype=np.int64) * 3 + 1   # all absent
     _, found = assert_probe_parity(table, queries)
     assert not found.any()
 
 
+def test_bucket_ladder():
+    tile = 1024
+    assert [bucket(n, tile) for n in (0, 1, tile, tile + 1, 2 * tile,
+                                      3 * tile, 4 * tile + 1)] \
+        == [tile, tile, tile, 2 * tile, 2 * tile, 4 * tile, 8 * tile]
+
+
+def test_sizes_in_one_bucket_share_one_compiled_program():
+    """Padding up the bucket ladder means a new size inside a bucket is a
+    cache hit, not a compile."""
+    rng = np.random.default_rng(6)
+    probe(np.arange(TABLE_TILE + 3), rng.integers(0, 99, 5),
+          impl="interpret")
+    n_probe = sorted_probe._cache_size()
+    probe(np.arange(2 * TABLE_TILE - 1), rng.integers(0, 99, QUERY_BLOCK),
+          impl="interpret")
+    assert sorted_probe._cache_size() == n_probe
+    aggregate(rng.integers(0, 9, 10).astype(np.int32), np.ones((10, 1)), 9,
+              impl="interpret")
+    n_agg = window_agg._cache_size()
+    aggregate(rng.integers(0, 300, EVENT_TILE).astype(np.int32),
+              np.ones((EVENT_TILE, 1)), SEG_BLOCK - 1, impl="interpret")
+    assert window_agg._cache_size() == n_agg
+
+
 # -------------------------------------------------------------- window_agg
 def assert_agg_parity(seg, vals, n_segments):
-    s1, c1 = aggregate(jnp.asarray(seg), jnp.asarray(vals), n_segments)
-    s2, c2 = aggregate(jnp.asarray(seg), jnp.asarray(vals), n_segments,
-                       impl="ref")
-    np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), atol=1e-3)
-    np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
-    return np.asarray(s1), np.asarray(c1)
+    s1, c1 = aggregate(seg, vals, n_segments, impl="interpret")
+    s2, c2 = aggregate(seg, vals, n_segments, impl="ref")
+    np.testing.assert_allclose(s1, s2, atol=1e-3)
+    np.testing.assert_array_equal(c1, c2)
+    return s1, c1
 
 
 def test_agg_empty_events():
@@ -120,25 +196,36 @@ def test_agg_single_segment_all_duplicates():
 def test_agg_segment_count_off_tile():
     """n_segments just past a SEG_BLOCK boundary; events off EVENT_TILE."""
     rng = np.random.default_rng(5)
-    seg = rng.integers(0, 513, 1025).astype(np.int32)
-    vals = rng.normal(size=(1025, 2)).astype(np.float32)
-    assert_agg_parity(seg, vals, 513)
+    seg = rng.integers(0, SEG_BLOCK + 1, EVENT_TILE + 1).astype(np.int32)
+    vals = rng.normal(size=(EVENT_TILE + 1, 2)).astype(np.float32)
+    assert_agg_parity(seg, vals, SEG_BLOCK + 1)
 
 
-# ------------------------------------------- LSM store dispatch: pallas path
-def test_store_pallas_impl_matches_numpy_oracle():
-    """The columnar store's get/put/flush behavior must not depend on which
-    kernel backend serves its probes and weight sums."""
+def test_agg_integer_weights_sum_exactly():
+    """The store's weight sums: integer counts far past bfloat16's 8-bit
+    mantissa must come back exact."""
+    rng = np.random.default_rng(12)
+    seg = np.sort(rng.integers(0, 700, 5000)).astype(np.int32)
+    w = rng.integers(1, 4000, (5000, 1)).astype(np.float32)
+    sums, _ = assert_agg_parity(seg, w, 700)
+    np.testing.assert_array_equal(
+        sums[:, 0], np.bincount(seg, weights=w[:, 0], minlength=700))
+
+
+# ------------------------------------------ LSM store dispatch: kernel path
+def assert_store_impls_agree(lo: int, hi: int) -> None:
+    """Same writes and reads, keys drawn from [lo, hi), into a numpy store
+    and a kernel-backed store: every answer and metric must agree."""
     rng = np.random.default_rng(11)
     a = LSMStore(0.5, value_words=2, kernel_impl="numpy")
-    b = LSMStore(0.5, value_words=2, kernel_impl="pallas")
+    b = LSMStore(0.5, value_words=2, kernel_impl="interpret")
     for step in range(6):
         n = int(rng.integers(1, 800))
-        keys = rng.integers(0, 2_000, n).astype(np.int64)
+        keys = rng.integers(lo, hi, n).astype(np.int64)
         vals = rng.integers(0, 1 << 30, (n, 2)).astype(np.int32)
         a.put_batch(keys, vals)
         b.put_batch(keys, vals)
-        q = rng.integers(0, 2_500, 300).astype(np.int64)
+        q = rng.integers(lo, hi + 500, 300).astype(np.int64)
         ga, fa = a.get_batch(q)
         gb, fb = b.get_batch(q)
         np.testing.assert_array_equal(fa, fb, err_msg=str(step))
@@ -148,3 +235,27 @@ def test_store_pallas_impl_matches_numpy_oracle():
     kb, vb = b.items()
     np.testing.assert_array_equal(ka, kb)
     np.testing.assert_array_equal(va, vb)
+
+
+def test_store_pallas_impl_matches_numpy_oracle():
+    """The columnar store's get/put/flush behavior must not depend on which
+    kernel backend serves its probes and weight sums."""
+    assert_store_impls_agree(0, 2_000)
+
+
+@pytest.mark.parametrize("lo,hi", [(-2_000, 2_000),
+                                   ((1 << 38) - 1_000, (1 << 38) + 1_000)])
+def test_store_kernel_impl_matches_numpy_oracle_on_int64_keys(lo, hi):
+    """Negative keys, and q8-scale keys above 2^31."""
+    assert_store_impls_agree(lo, hi)
+
+
+def test_pallas_impl_refuses_to_run_off_the_tpu():
+    """Compiled kernels never fall back to the interpreter or numpy."""
+    with pytest.raises(RuntimeError, match="TPU"):
+        LSMStore(0.5, kernel_impl="pallas")
+    with pytest.raises(RuntimeError, match="TPU"):
+        lsm.set_kernel_impl("pallas")
+    assert lsm.DEFAULT_KERNEL_IMPL == "numpy"
+    with pytest.raises(ValueError):
+        LSMStore(0.5, kernel_impl="cuda")

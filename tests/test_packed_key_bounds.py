@@ -106,8 +106,8 @@ def test_fast_path_serves_key_at_band_edge():
 
 def test_fast_and_fallback_paths_agree_key_for_key():
     # Same writes into a numpy-kernel store (fast packed probe) and a
-    # pallas-kernel store (always per-run fallback): reads must agree.
-    a, b = _store(kernel_impl="numpy"), _store(kernel_impl="pallas")
+    # kernel-backed store (always per-run fallback): reads must agree.
+    a, b = _store(kernel_impl="numpy"), _store(kernel_impl="interpret")
     rng = np.random.default_rng(4)
     for _ in range(5):
         keys = rng.integers(0, 200, 48)
