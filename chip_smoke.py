@@ -161,6 +161,7 @@ def main() -> None:
     dev = device()
     log(f"device: {dev['kind']} x{dev['count']} ({dev['platform']})")
     from repro.kernels import device as kdev
+    from repro.obs import counts
     log(f"compile cache: {kdev.use_compile_cache()}")
     clock = CompileClock()
     from repro.kernels.sorted_probe.kernel import sorted_probe
@@ -172,7 +173,7 @@ def main() -> None:
     log(f"  store phase seconds: {time.perf_counter() - t0:.3f}")
 
     log("phase c: q8-justin episode on the pallas store")
-    calls0, comp0 = dict(kdev.dispatches), (clock.seconds, clock.count)
+    calls0, comp0 = dict(counts), (clock.seconds, clock.count)
     got, seconds = episode()
     want = GOLDEN["q8_justin"]
     for field in ("steps", "windows", "configs", "triggered", "cpu_cores",
@@ -183,8 +184,8 @@ def main() -> None:
 
     log("phase d: report")
     log(f"  episode wall seconds: {seconds:.3f}")
-    for k in ("sorted_probe", "window_agg"):
-        n = kdev.dispatches[k]
+    for k in ("sorted_probe.calls", "window_agg.calls"):
+        n = counts[k]
         log(f"  device calls {k}: episode {n - calls0.get(k, 0)}, "
             f"whole run {n}")
     programs = {"sorted_probe": sorted_probe._cache_size(),

@@ -6,12 +6,8 @@ persistent compile cache.
 """
 from __future__ import annotations
 
-import collections
 import os
 import pathlib
-
-# device calls per kernel in this process (``chip_smoke.py`` reports them)
-dispatches: collections.Counter = collections.Counter()
 
 
 def require_tpu() -> None:

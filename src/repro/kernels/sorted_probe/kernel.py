@@ -69,5 +69,6 @@ def sorted_probe(t_hi: jax.Array, t_lo: jax.Array, q_hi: jax.Array,
         out_specs=[query_spec, query_spec],
         out_shape=[jax.ShapeDtypeStruct((n, LANES), jnp.int32)] * 2,
         interpret=interpret,
+        name="sorted_probe",
     )(t_hi, t_lo, q_hi, q_lo)
     return jnp.sum(lt, axis=1), jnp.sum(eq, axis=1) > 0

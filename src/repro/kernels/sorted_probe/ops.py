@@ -1,10 +1,19 @@
-"""Host-side wrapper for the sorted-run probe."""
+"""Host-side wrapper for the sorted-run probe.
+
+A device call opens three spans (``repro.obs.spans``):
+``sorted_probe.prepare`` (padding and key split on the host),
+``sorted_probe.launch`` (the jitted call, which stages the host operands
+and launches the kernel) and ``sorted_probe.wait`` (copying the results
+back, which waits for the device), and counts the call and the bytes
+it sends.
+"""
 from __future__ import annotations
 
 import numpy as np
 
-from repro.kernels.device import bucket, dispatches
+from repro.kernels.device import bucket
 from repro.kernels.sorted_probe.ref import sorted_probe_ref
+from repro.obs.spans import counts, first_call, span
 
 INT64_MAX = np.iinfo(np.int64).max
 
@@ -39,12 +48,18 @@ def probe(table: np.ndarray, queries: np.ndarray, *, impl: str
         raise ValueError(f"unknown probe impl {impl!r}")
     from repro.kernels.sorted_probe.kernel import (QUERY_BLOCK, TABLE_TILE,
                                                    sorted_probe)
-    tb = np.full(bucket(t, TABLE_TILE), INT64_MAX)
-    tb[:t] = table
-    qb = np.full(bucket(n, QUERY_BLOCK), INT64_MAX)
-    qb[:n] = queries
-    dispatches["sorted_probe"] += 1
-    pos, found = sorted_probe(*split_keys(tb), *split_keys(qb),
-                              interpret=impl == "interpret")
-    pos = np.asarray(pos)[:n]
-    return pos, np.asarray(found)[:n] & (pos < t)
+    tp, qp = bucket(t, TABLE_TILE), bucket(n, QUERY_BLOCK)
+    with span("sorted_probe.prepare"):
+        tb = np.full(tp, INT64_MAX)
+        tb[:t] = table
+        qb = np.full(qp, INT64_MAX)
+        qb[:n] = queries
+        words = (*split_keys(tb), *split_keys(qb))
+    with span("sorted_probe.launch"), first_call("sorted_probe", (tp, qp)):
+        pos, found = sorted_probe(*words, interpret=impl == "interpret")
+    with span("sorted_probe.wait"):
+        pos = np.asarray(pos)[:n]
+        found = np.asarray(found)[:n]
+    counts["sorted_probe.calls"] += 1
+    counts["sorted_probe.h2d_bytes"] += 8 * (tp + qp)   # two int32 words a key
+    return pos, found & (pos < t)

@@ -72,5 +72,6 @@ def window_agg(seg_ids: jax.Array, values: jax.Array, n_segments: int, *,
         out_shape=jax.ShapeDtypeStruct((v, n_segments + s_pad), jnp.float32),
         scratch_shapes=[pltpu.VMEM((v, SEG_BLOCK, LANES), jnp.float32)],
         interpret=interpret,
+        name="window_agg",
     )(seg_ids.reshape(-1, LANES), values.reshape(v, -1, LANES))
     return sums[:, :n_segments]

@@ -1,10 +1,19 @@
-"""Host-side wrapper for keyed window aggregation."""
+"""Host-side wrapper for keyed window aggregation.
+
+A device call opens three spans (``repro.obs.spans``):
+``window_agg.prepare`` (padding and the transpose to lane-major rows on
+the host), ``window_agg.launch`` (the jitted call, which stages the host
+operands and launches the kernel) and ``window_agg.wait`` (copying the
+sums back, which waits for the device), and counts the call and the
+bytes it sends.
+"""
 from __future__ import annotations
 
 import numpy as np
 
-from repro.kernels.device import bucket, dispatches
+from repro.kernels.device import bucket
 from repro.kernels.window_agg.ref import window_agg_ref
+from repro.obs.spans import counts, first_call, span
 
 
 def aggregate(seg_ids: np.ndarray, values: np.ndarray, n_segments: int, *,
@@ -29,13 +38,17 @@ def aggregate(seg_ids: np.ndarray, values: np.ndarray, n_segments: int, *,
         raise ValueError(f"unknown aggregate impl {impl!r}")
     from repro.kernels.window_agg.kernel import (EVENT_TILE, SEG_BLOCK,
                                                  window_agg)
-    nb = bucket(n, EVENT_TILE)
-    seg = np.full(nb, -1, np.int32)
-    seg[:n] = seg_ids
-    rows = np.zeros((v, nb), np.float32)
-    rows[:, :n] = values.T
-    dispatches["window_agg"] += 1
-    sums = np.asarray(window_agg(seg, rows, bucket(n_segments, SEG_BLOCK),
-                                 interpret=impl == "interpret"))
-    counts = np.bincount(seg_ids, minlength=n_segments)
-    return sums[:, :n_segments].T, counts.astype(np.float32)
+    nb, sb = bucket(n, EVENT_TILE), bucket(n_segments, SEG_BLOCK)
+    with span("window_agg.prepare"):
+        seg = np.full(nb, -1, np.int32)
+        seg[:n] = seg_ids
+        rows = np.zeros((v, nb), np.float32)
+        rows[:, :n] = values.T
+    with span("window_agg.launch"), first_call("window_agg", (nb, sb, v)):
+        sums = window_agg(seg, rows, sb, interpret=impl == "interpret")
+    with span("window_agg.wait"):
+        sums = np.asarray(sums)
+    counts["window_agg.calls"] += 1
+    counts["window_agg.h2d_bytes"] += 4 * nb * (1 + v)  # int32 ids, f32 rows
+    per_seg = np.bincount(seg_ids, minlength=n_segments)
+    return sums[:, :n_segments].T, per_seg.astype(np.float32)

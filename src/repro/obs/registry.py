@@ -8,9 +8,10 @@ instead of ad-hoc ``time.time()`` reads, so BENCH_*.json and traces
 report from one clock path.
 
 Disabled path is O(1): a disabled registry hands out one shared no-op
-instrument, so instrumented code needs no ``if enabled`` guards.  Like
-``obs.trace``, the wall clock lives only here (timers) — golden modules
-never construct or read a registry (reprolint T501/R305 enforce it).
+instrument, so instrumented code needs no ``if enabled`` guards.  The
+one wall-clock read of ``repro.obs`` lives here (timers) — golden
+modules never construct or read a registry (reprolint T501/R305
+enforce it).
 """
 from __future__ import annotations
 

@@ -15,16 +15,13 @@ Determinism contract:
   back to.
 * ``record`` never reads engine RNG or mutates anything a decision
   reads; span ``args`` are copied into fresh dicts at record time.
-* The one wall-clock read lives behind ``self_profile=True`` and flows
-  ONLY into ``overhead_s`` (how much wall time tracing itself cost).
-  reprolint's T501 obs scope proves statically that no value returned by
-  this module reaches a golden-module decision: golden modules may call
-  ``record`` only as a discarded expression statement
+* This module reads no clock.  Wall time is :mod:`repro.obs.spans`'s
+  job, on the profiler's clock.  reprolint's T501 pass proves statically
+  that nothing reachable from here reads a nondeterminism source
   (docs/static-analysis.md).
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 
 # span categories — the schema contract tools/check_trace.py validates
@@ -52,18 +49,11 @@ class Span:
 
 
 class Tracer:
-    """Collects :class:`Span` records; disabled path is O(1).
+    """Collects :class:`Span` records; disabled path is O(1)."""
 
-    ``self_profile=True`` additionally measures the wall-clock overhead
-    of tracing itself into ``overhead_s`` — the only ``time`` read in
-    this module, and it never leaves the tracer.
-    """
-
-    def __init__(self, enabled: bool = True, self_profile: bool = False):
+    def __init__(self, enabled: bool = True):
         self.enabled = enabled
-        self.self_profile = self_profile
         self.spans: list[Span] = []
-        self.overhead_s = 0.0
         self._seq = 0
 
     def record(self, name: str, cat: str, t0: float, t1: float,
@@ -74,18 +64,14 @@ class Tracer:
         discarded-call discipline (T501 obs scope)."""
         if not self.enabled:
             return
-        wall = time.perf_counter() if self.self_profile else None
         self.spans.append(Span(self._seq, name, cat, float(t0), float(t1),
                                tenant, window,
                                dict(args) if args else {}))
         self._seq += 1
-        if wall is not None:
-            self.overhead_s += time.perf_counter() - wall
 
     def clear(self) -> None:
         self.spans = []
         self._seq = 0
-        self.overhead_s = 0.0
 
     def summary(self) -> dict[str, dict]:
         """Per-(tenant, cat, name) aggregate: span count and total sim

@@ -59,6 +59,8 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from repro.obs.spans import span
+
 LOGICAL_ENTRY_BYTES = 1_000          # paper §3: 1000 B events
 MEMTABLE_GRANULARITY_MB = 64         # first-level SSTable size (paper §3)
 CACHE_OVERHEAD = 2.5                 # block granularity + index/filter share
@@ -323,27 +325,29 @@ class LSMStore:
                    ) -> tuple[np.ndarray, np.ndarray]:
         """Batched sorted-run rank: (clipped position, hit mask).  Positions
         are only meaningful where ``hit`` — there they index the match."""
-        if self.kernel_impl != "numpy":
-            from repro.kernels.sorted_probe.ops import probe
-            pos, hit = probe(run_keys, queries, impl=self.kernel_impl)
-            return np.minimum(pos.astype(np.int64),
-                              max(len(run_keys) - 1, 0)), hit
-        pos = np.searchsorted(run_keys, queries)
-        pos_c = np.minimum(pos, len(run_keys) - 1)
-        hit = (run_keys[pos_c] == queries) & (pos < len(run_keys))
-        return pos_c, hit
+        with span("lsm.probe"):
+            if self.kernel_impl != "numpy":
+                from repro.kernels.sorted_probe.ops import probe
+                pos, hit = probe(run_keys, queries, impl=self.kernel_impl)
+                return np.minimum(pos.astype(np.int64),
+                                  max(len(run_keys) - 1, 0)), hit
+            pos = np.searchsorted(run_keys, queries)
+            pos_c = np.minimum(pos, len(run_keys) - 1)
+            hit = (run_keys[pos_c] == queries) & (pos < len(run_keys))
+            return pos_c, hit
 
     def _segment_sum(self, sorted_w: np.ndarray, starts: np.ndarray,
                      first_mask: np.ndarray) -> np.ndarray:
         """Per-unique-key weight sum over key-sorted deltas — the
         consolidation reduction (``window_agg`` kernel on TPU)."""
-        if self.kernel_impl != "numpy":
-            from repro.kernels.window_agg.ops import aggregate
-            gids = (np.cumsum(first_mask) - 1).astype(np.int32)
-            sums, _ = aggregate(gids, sorted_w.astype(np.float32)[:, None],
-                                len(starts), impl=self.kernel_impl)
-            return sums[:, 0].astype(np.int64)
-        return np.add.reduceat(sorted_w, starts)
+        with span("lsm.segment_sum"):
+            if self.kernel_impl != "numpy":
+                from repro.kernels.window_agg.ops import aggregate
+                gids = (np.cumsum(first_mask) - 1).astype(np.int32)
+                sums, _ = aggregate(gids, sorted_w.astype(np.float32)[:, None],
+                                    len(starts), impl=self.kernel_impl)
+                return sums[:, 0].astype(np.int64)
+            return np.add.reduceat(sorted_w, starts)
 
     # ------------------------------------------------------------- write path
     @staticmethod
@@ -370,30 +374,31 @@ class LSMStore:
         caller probing a monotone transform of the same key batch can
         reuse the sort via ``get_batch``'s ``uhint`` (DBSP idiom: sort a
         batch once, feed every operator from the same arrangement)."""
-        n = len(keys)
-        self.metrics.writes += n
-        self.metrics.access_latency_total_ms += \
-            n * self.latency.write_ms * self._wscale
-        uq, w, uv = self._delta_of(keys, vals)   # shared by runs + cache
-        if n <= self.memtable_cap - self.mem_n:  # fast path: fits in room
-            self.mem_n += n
-            self._append_delta(uq, w, uv)
-            if self.mem_n >= self.memtable_cap:
-                self._flush()
-        else:                                    # crosses flush boundaries
-            off = 0
-            while off < n:
-                room = self.memtable_cap - self.mem_n
-                take = min(room, n - off)
-                sl = slice(off, off + take)
-                self.mem_n += take
-                off += take
-                self._append_delta(*self._delta_of(keys[sl], vals[sl]))
+        with span("lsm.put_batch"):
+            n = len(keys)
+            self.metrics.writes += n
+            self.metrics.access_latency_total_ms += \
+                n * self.latency.write_ms * self._wscale
+            uq, w, uv = self._delta_of(keys, vals)   # shared by runs + cache
+            if n <= self.memtable_cap - self.mem_n:  # fast path: fits in room
+                self.mem_n += n
+                self._append_delta(uq, w, uv)
                 if self.mem_n >= self.memtable_cap:
                     self._flush()
-        # write-through invalidate/update of cached copies
-        self._cache_apply(uq, uv)
-        return uq, w, uv
+            else:                                    # crosses flush boundaries
+                off = 0
+                while off < n:
+                    room = self.memtable_cap - self.mem_n
+                    take = min(room, n - off)
+                    sl = slice(off, off + take)
+                    self.mem_n += take
+                    off += take
+                    self._append_delta(*self._delta_of(keys[sl], vals[sl]))
+                    if self.mem_n >= self.memtable_cap:
+                        self._flush()
+            # write-through invalidate/update of cached copies
+            self._cache_apply(uq, uv)
+            return uq, w, uv
 
     def _append_delta(self, uq: np.ndarray, w: np.ndarray, uv: np.ndarray
                       ) -> None:
@@ -435,12 +440,13 @@ class LSMStore:
         least half its size) — amortized O(n log n) per memtable epoch."""
         if not self._runs:
             return
-        self._tiers.insert(0, self._collapse(self._runs))
-        self._runs = []
-        while (len(self._tiers) > 1
-               and 2 * len(self._tiers[0][0]) >= len(self._tiers[1][0])):
-            newer = self._tiers.pop(0)
-            self._tiers[0] = merge_delta_runs(*newer, *self._tiers[0])
+        with span("lsm.consolidate"):
+            self._tiers.insert(0, self._collapse(self._runs))
+            self._runs = []
+            while (len(self._tiers) > 1
+                   and 2 * len(self._tiers[0][0]) >= len(self._tiers[1][0])):
+                newer = self._tiers.pop(0)
+                self._tiers[0] = merge_delta_runs(*newer, *self._tiers[0])
 
     def _memtable_merged(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Full memtable content: sorted unique keys, summed weights, newest
@@ -474,27 +480,29 @@ class LSMStore:
         """Engine state-install entry point: ``keys`` already key-sorted
         (the re-partitioning path pre-sorts), installed as one run with
         size-tiered compaction applied."""
-        if weights is None:
-            weights = np.ones(len(keys), np.int64)
-        self._push_run(keys, weights, vals)
+        with span("lsm.install_run"):
+            if weights is None:
+                weights = np.ones(len(keys), np.int64)
+            self._push_run(keys, weights, vals)
 
     def _flush(self) -> None:
         if self.mem_n == 0:
             return
-        uniq, wts, fvals = self._memtable_merged()
-        if self.compact_filter is not None and len(uniq):
-            keep = self.compact_filter(uniq)
-            if not keep.all():
-                self.annihilated += int(wts[~keep].sum())
-                uniq, wts, fvals = uniq[keep], wts[keep], fvals[keep]
-        self._push_run(uniq, wts, fvals)
-        self.mem_n = 0
-        self._runs = []
-        self._tiers = []
-        self.metrics.flushes += 1
-        self.metrics.access_latency_total_ms += \
-            (len(uniq) * self.latency.flush_ms
-             + self.latency.flush_fixed_ms) * self._wscale
+        with span("lsm.flush"):
+            uniq, wts, fvals = self._memtable_merged()
+            if self.compact_filter is not None and len(uniq):
+                keep = self.compact_filter(uniq)
+                if not keep.all():
+                    self.annihilated += int(wts[~keep].sum())
+                    uniq, wts, fvals = uniq[keep], wts[keep], fvals[keep]
+            self._push_run(uniq, wts, fvals)
+            self.mem_n = 0
+            self._runs = []
+            self._tiers = []
+            self.metrics.flushes += 1
+            self.metrics.access_latency_total_ms += \
+                (len(uniq) * self.latency.flush_ms
+                 + self.latency.flush_fixed_ms) * self._wscale
 
     def _push_run(self, keys: np.ndarray, weights: np.ndarray,
                   vals: np.ndarray) -> None:
@@ -510,19 +518,20 @@ class LSMStore:
                 i += 1
 
     def _merge_levels(self, i: int) -> None:
-        k1, w1, v1 = self.levels[i]          # newer
-        k2, w2, v2 = self.levels[i + 1]      # older
-        n_in = len(k1) + len(k2)
-        uniq, wts, vals = merge_delta_runs(k1, w1, v1, k2, w2, v2)
-        if self.compact_filter is not None and len(uniq):
-            keep = self.compact_filter(uniq)
-            if not keep.all():
-                self.annihilated += int(wts[~keep].sum())
-                uniq, wts, vals = uniq[keep], wts[keep], vals[keep]
-        self.levels[i + 1] = (uniq, wts, vals)
-        del self.levels[i]
-        self.metrics.access_latency_total_ms += \
-            n_in * self.latency.compact_ms * self._wscale
+        with span("lsm.compact"):
+            k1, w1, v1 = self.levels[i]          # newer
+            k2, w2, v2 = self.levels[i + 1]      # older
+            n_in = len(k1) + len(k2)
+            uniq, wts, vals = merge_delta_runs(k1, w1, v1, k2, w2, v2)
+            if self.compact_filter is not None and len(uniq):
+                keep = self.compact_filter(uniq)
+                if not keep.all():
+                    self.annihilated += int(wts[~keep].sum())
+                    uniq, wts, vals = uniq[keep], wts[keep], vals[keep]
+            self.levels[i + 1] = (uniq, wts, vals)
+            del self.levels[i]
+            self.metrics.access_latency_total_ms += \
+                n_in * self.latency.compact_ms * self._wscale
 
     # -------------------------------------------------------------- read path
     def get_batch(self, keys: np.ndarray,
@@ -545,178 +554,202 @@ class LSMStore:
         just-admitted block, duplicates of absent keys re-walk the bloom
         filters.  Per-call metric equality on arbitrary batches vs the
         chunked seed is NOT claimed — golden-trace decision equality is."""
-        n = len(keys)
-        self.metrics.reads += n
-        lat = 0.0
-        # every tier below works on unique keys: all occurrences of a key
-        # resolve identically, so probe once and scatter through ``inv`` at
-        # the end — occurrence-level metric charges recovered via ``cnts``
-        if uhint is None:
-            uq, inv, cnts = np.unique(keys, return_inverse=True,
-                                      return_counts=True)
-        else:
-            uq, cnts = uhint
-            inv = np.searchsorted(uq, keys)
-        uvals = np.zeros((len(uq), self.value_words), np.int32)
-        ufound = np.zeros(len(uq), bool)
-
-        # 1. memtable: probe delta runs newest-first, then the tiers — the
-        # first run containing a key holds its newest payload.  One
-        # source-major searchsorted covers every run at once (see
-        # _mem_concat); the per-run loop remains as the fallback for the
-        # device kernel dispatch and out-of-range keys.  Both find the same
-        # key set with the same newest payload, so θ/τ charges agree.
-        if self.mem_n:
-            T = None
-            if self.kernel_impl == "numpy":
-                T, offs, srcs = self._mem_concat()
-            fast = False
-            if T is not None and len(T) and len(uq):
-                # stored keys are in [0, 2^45) (else _mem_concat bailed),
-                # but QUERY keys arrive unchecked: a query outside that
-                # range would land in another source's band after packing
-                # and false-hit its keys, so such batches (and empty
-                # query sets) take the per-run fallback below
-                lim = np.int64(1) << self._MEM_SHIFT
-                fast = bool(int(uq[0]) >= 0 and int(uq[-1]) < lim)
-            if fast:
-                R = len(srcs)
-                assert R < (1 << 18)   # source ids share the 63-45 headroom
-                nu = len(uq)
-                qq = ((np.arange(R, dtype=np.int64)[:, None]
-                       << self._MEM_SHIFT) + uq[None, :]).ravel()
-                pos = np.searchsorted(T, qq)
-                np.minimum(pos, len(T) - 1, out=pos)
-                hit = (T[pos] == qq).reshape(R, nu)[::-1]  # newest first
-                si = hit.argmax(axis=0)
-                fnd = hit[si, np.arange(nu)]
-                fidx = np.flatnonzero(fnd)
-                if len(fidx):
-                    src = R - 1 - si[fidx]          # undo the flip
-                    ufound[fidx] = True
-                    self.metrics.memtable_hits += int(cnts[fidx].sum())
-                    posm = pos.reshape(R, nu)
-                    for i in np.flatnonzero(np.bincount(src, minlength=R)):
-                        sel = fidx[src == i]
-                        uvals[sel] = srcs[i][2][posm[i, sel] - offs[i]]
+        with span("lsm.get_batch"):
+            n = len(keys)
+            self.metrics.reads += n
+            lat = 0.0
+            # every tier below works on unique keys: all occurrences of a key
+            # resolve identically, so probe once and scatter through ``inv`` at
+            # the end — occurrence-level metric charges recovered via ``cnts``
+            if uhint is None:
+                uq, inv, cnts = np.unique(keys, return_inverse=True,
+                                          return_counts=True)
             else:
-                mem_hits = 0
-                pending = None               # None => every key outstanding
-                for rk, _w, rv in self._mem_probe_order():
-                    if not len(rk):
-                        continue
-                    if pending is None:
-                        tk = uq
-                    else:
-                        if not len(pending):
-                            break
-                        tk = uq[pending]
-                    pos, hit = self._probe_run(rk, tk)
-                    hidx = np.flatnonzero(hit)
-                    if len(hidx):
-                        idx = hidx if pending is None else pending[hidx]
-                        uvals[idx] = rv[pos[hidx]]
-                        ufound[idx] = True
-                        mem_hits += int(cnts[idx].sum())   # per-occurrence
-                    pending = np.flatnonzero(~hit) if pending is None \
-                        else pending[~hit]
-                self.metrics.memtable_hits += mem_hits
-        lat += n * self.latency.memtable_ms
+                uq, cnts = uhint
+                inv = np.searchsorted(uq, keys)
+            uvals = np.zeros((len(uq), self.value_words), np.int32)
+            ufound = np.zeros(len(uq), bool)
 
-        # 2. block cache — probed once per *unique* key (see docstring).
-        if not ufound.all():
-            sub = np.flatnonzero(~ufound)
-            uk = uq[sub]
-            n_todo = n - int(cnts[ufound].sum())   # unfound occurrences
-            sets = self._sets(uk)
-            match = self.cache_keys[sets] == uk[:, None]        # [u, ways]
-            # argmax-then-gather: one reduction pass instead of any+argmax
-            # (axis-wise ``any`` costs a full second pass; an all-False row
-            # argmaxes to way 0 where the gather reads False)
-            way = match.argmax(axis=1)
-            hit = match[np.arange(len(uk)), way]
-            hi = np.flatnonzero(hit)
-            sh, wh = sets[hi], way[hi]
-            ckvals = np.zeros((len(uk), self.value_words), np.int32)
-            ckvals[hi] = self.cache_vals[sh, wh]
-            ckfound = hit           # safe alias: ~hit is consumed (rem)
-                                    # before ckfound's only mutation below
-            self.cache_ref[sh, wh] = 1
-            self.metrics.cache_hits += len(hi)
-            self.metrics.cache_misses += len(uk) - len(hi)
-            lat += len(uk) * self.latency.cache_ms
+            # 1. memtable: probe delta runs newest-first, then the tiers — the
+            # first run containing a key holds its newest payload.  One
+            # source-major searchsorted covers every run at once (see
+            # _mem_concat); the per-run loop remains as the fallback for the
+            # device kernel dispatch and out-of-range keys.  Both find the same
+            # key set with the same newest payload, so θ/τ charges agree.
+            if self.mem_n:
+                with span("lsm.read.memtable"):
+                    self._read_memtable(uq, cnts, uvals, ufound)
+            lat += n * self.latency.memtable_ms
 
-            # 3. levels (slow tier) for cache misses.  Bloom filters guard
-            # each SSTable: absent keys cost a filter check (plus the
-            # false-positive rate of real probes) instead of a full read.
-            rem = np.where(~hit)[0]
-            if len(rem):
-                probe_keys = uk[rem]
-                got = np.zeros(len(rem), bool)
-                gvals = np.zeros((len(rem), self.value_words), np.int32)
-                probes = 0.0
-                blooms = 0
-                for (lk, _lw, lv) in self.levels:
-                    lidx = np.flatnonzero(~got)
-                    n_live = len(lidx)
-                    if not n_live:
+            # 2. block cache — probed once per *unique* key (see docstring).
+            if not ufound.all():
+                sub = np.flatnonzero(~ufound)
+                uk = uq[sub]
+                n_todo = n - int(cnts[ufound].sum())   # unfound occurrences
+                with span("lsm.read.cache"):
+                    sets = self._sets(uk)
+                    match = self.cache_keys[sets] == uk[:, None]  # [u, ways]
+                    # argmax-then-gather: one reduction pass instead of
+                    # any+argmax (axis-wise ``any`` costs a full second
+                    # pass; an all-False row argmaxes to way 0 where the
+                    # gather reads False)
+                    way = match.argmax(axis=1)
+                    hit = match[np.arange(len(uk)), way]
+                    hi = np.flatnonzero(hit)
+                    sh, wh = sets[hi], way[hi]
+                    ckvals = np.zeros((len(uk), self.value_words), np.int32)
+                    ckvals[hi] = self.cache_vals[sh, wh]
+                    # safe alias: ~hit is consumed (rem) before ckfound's
+                    # only mutation below
+                    ckfound = hit
+                    self.cache_ref[sh, wh] = 1
+                    self.metrics.cache_hits += len(hi)
+                    self.metrics.cache_misses += len(uk) - len(hi)
+                lat += len(uk) * self.latency.cache_ms
+
+                # 3. levels (slow tier) for cache misses.  Bloom filters guard
+                # each SSTable: absent keys cost a filter check (plus the
+                # false-positive rate of real probes) instead of a full read.
+                rem = np.where(~hit)[0]
+                if len(rem):
+                    with span("lsm.read.levels"):
+                        got, gvals, lv_ms = self._read_levels(uk[rem])
+                    ckvals[rem[got]] = gvals[got]
+                    ckfound[rem[got]] = True
+                    lat += lv_ms
+
+                uvals[sub] = ckvals
+                ufound[sub] = ckfound
+                n_dup = n_todo - len(uk)
+                if n_dup:
+                    res_dups = int((cnts[sub][ckfound] - 1).sum())
+                    unres_dups = n_dup - res_dups
+                    # resolved duplicates hit the (possibly just-admitted)
+                    # block
+                    self.metrics.cache_hits += res_dups
+                    self.metrics.cache_misses += unres_dups
+                    lat += n_dup * self.latency.cache_ms
+                    if unres_dups:
+                        probes = 0.0
+                        for (lk, _lw, _lv) in self.levels:
+                            meta_ws = max(1.0,
+                                          len(lk) / self.latency.meta_ratio)
+                            meta_cover = min(1.0,
+                                             self.cache_capacity / meta_ws)
+                            probes += (self.latency.bloom_fp
+                                       + (1.0 - meta_cover)
+                                       * self.latency.meta_read_frac
+                                       ) * unres_dups
+                        self.metrics.level_probes += int(probes)
+                        lat += (probes * self.latency.level_ms + unres_dups
+                                * len(self.levels) * self.latency.bloom_ms)
+
+            self.metrics.access_latency_total_ms += lat
+            return uvals[inv], ufound[inv]
+
+    def _read_memtable(self, uq: np.ndarray, cnts: np.ndarray,
+                       uvals: np.ndarray, ufound: np.ndarray) -> None:
+        """The memtable tier of ``get_batch``: fills ``uvals``/``ufound``
+        for the sorted-unique keys ``uq`` (occurrence counts ``cnts``)
+        that some memtable run holds."""
+        T = None
+        if self.kernel_impl == "numpy":
+            T, offs, srcs = self._mem_concat()
+        fast = False
+        if T is not None and len(T) and len(uq):
+            # stored keys are in [0, 2^45) (else _mem_concat bailed),
+            # but QUERY keys arrive unchecked: a query outside that
+            # range would land in another source's band after packing
+            # and false-hit its keys, so such batches (and empty
+            # query sets) take the per-run fallback below
+            lim = np.int64(1) << self._MEM_SHIFT
+            fast = bool(int(uq[0]) >= 0 and int(uq[-1]) < lim)
+        if fast:
+            R = len(srcs)
+            assert R < (1 << 18)   # source ids share the 63-45 headroom
+            nu = len(uq)
+            qq = ((np.arange(R, dtype=np.int64)[:, None]
+                   << self._MEM_SHIFT) + uq[None, :]).ravel()
+            pos = np.searchsorted(T, qq)
+            np.minimum(pos, len(T) - 1, out=pos)
+            hit = (T[pos] == qq).reshape(R, nu)[::-1]  # newest first
+            si = hit.argmax(axis=0)
+            fnd = hit[si, np.arange(nu)]
+            fidx = np.flatnonzero(fnd)
+            if len(fidx):
+                src = R - 1 - si[fidx]          # undo the flip
+                ufound[fidx] = True
+                self.metrics.memtable_hits += int(cnts[fidx].sum())
+                posm = pos.reshape(R, nu)
+                for i in np.flatnonzero(np.bincount(src, minlength=R)):
+                    sel = fidx[src == i]
+                    uvals[sel] = srcs[i][2][posm[i, sel] - offs[i]]
+        else:
+            mem_hits = 0
+            pending = None               # None => every key outstanding
+            for rk, _w, rv in self._mem_probe_order():
+                if not len(rk):
+                    continue
+                if pending is None:
+                    tk = uq
+                else:
+                    if not len(pending):
                         break
-                    if len(lk):
-                        pos, h = self._probe_run(lk, probe_keys[lidx])
-                    else:
-                        h = np.zeros(n_live, bool)
-                        pos = h
-                    n_hit = int(h.sum())
-                    # present keys pass the bloom filter and read the block;
-                    # absent keys mostly stop at the filter — but the filter/
-                    # index blocks themselves need block-cache residency:
-                    # with a small cache a share of filter checks also hits
-                    # the slow tier (RocksDB filter-block eviction)
-                    meta_ws = max(1.0, len(lk) / self.latency.meta_ratio)
-                    meta_cover = min(1.0, self.cache_capacity / meta_ws)
-                    probes += n_hit + self.latency.bloom_fp * (n_live - n_hit)
-                    probes += (1.0 - meta_cover) \
-                        * self.latency.meta_read_frac * n_live
-                    blooms += n_live
-                    if n_hit:
-                        hh = np.flatnonzero(h)
-                        tgt = lidx[hh]
-                        gvals[tgt] = lv[pos[hh]]
-                        got[tgt] = True
-                ckvals[rem[got]] = gvals[got]
-                ckfound[rem[got]] = True
-                self.metrics.level_probes += int(probes)
-                lat += (probes * self.latency.level_ms
-                        + blooms * self.latency.bloom_ms)
-                # admit fetched entries into the cache (probe_keys is
-                # sorted-unique, so the deduping _cache_update is skipped)
-                if got.any():
-                    self._cache_apply(probe_keys[got], gvals[got],
-                                      fresh=True)
+                    tk = uq[pending]
+                pos, hit = self._probe_run(rk, tk)
+                hidx = np.flatnonzero(hit)
+                if len(hidx):
+                    idx = hidx if pending is None else pending[hidx]
+                    uvals[idx] = rv[pos[hidx]]
+                    ufound[idx] = True
+                    mem_hits += int(cnts[idx].sum())   # per-occurrence
+                pending = np.flatnonzero(~hit) if pending is None \
+                    else pending[~hit]
+            self.metrics.memtable_hits += mem_hits
 
-            uvals[sub] = ckvals
-            ufound[sub] = ckfound
-            n_dup = n_todo - len(uk)
-            if n_dup:
-                res_dups = int((cnts[sub][ckfound] - 1).sum())
-                unres_dups = n_dup - res_dups
-                # resolved duplicates hit the (possibly just-admitted) block
-                self.metrics.cache_hits += res_dups
-                self.metrics.cache_misses += unres_dups
-                lat += n_dup * self.latency.cache_ms
-                if unres_dups:
-                    probes = 0.0
-                    for (lk, _lw, _lv) in self.levels:
-                        meta_ws = max(1.0, len(lk) / self.latency.meta_ratio)
-                        meta_cover = min(1.0, self.cache_capacity / meta_ws)
-                        probes += (self.latency.bloom_fp + (1.0 - meta_cover)
-                                   * self.latency.meta_read_frac) * unres_dups
-                    self.metrics.level_probes += int(probes)
-                    lat += (probes * self.latency.level_ms + unres_dups
-                            * len(self.levels) * self.latency.bloom_ms)
-
-        self.metrics.access_latency_total_ms += lat
-        return uvals[inv], ufound[inv]
+    def _read_levels(self, probe_keys: np.ndarray
+                     ) -> tuple[np.ndarray, np.ndarray, float]:
+        """The level tier of ``get_batch`` for the sorted-unique keys
+        the cache missed: (found mask, payloads, latency charged in
+        ms), with the found entries admitted into the cache."""
+        got = np.zeros(len(probe_keys), bool)
+        gvals = np.zeros((len(probe_keys), self.value_words), np.int32)
+        probes = 0.0
+        blooms = 0
+        for (lk, _lw, lv) in self.levels:
+            lidx = np.flatnonzero(~got)
+            n_live = len(lidx)
+            if not n_live:
+                break
+            if len(lk):
+                pos, h = self._probe_run(lk, probe_keys[lidx])
+            else:
+                h = np.zeros(n_live, bool)
+                pos = h
+            n_hit = int(h.sum())
+            # present keys pass the bloom filter and read the block;
+            # absent keys mostly stop at the filter — but the filter/
+            # index blocks themselves need block-cache residency:
+            # with a small cache a share of filter checks also hits
+            # the slow tier (RocksDB filter-block eviction)
+            meta_ws = max(1.0, len(lk) / self.latency.meta_ratio)
+            meta_cover = min(1.0, self.cache_capacity / meta_ws)
+            probes += n_hit + self.latency.bloom_fp * (n_live - n_hit)
+            probes += (1.0 - meta_cover) \
+                * self.latency.meta_read_frac * n_live
+            blooms += n_live
+            if n_hit:
+                hh = np.flatnonzero(h)
+                tgt = lidx[hh]
+                gvals[tgt] = lv[pos[hh]]
+                got[tgt] = True
+        self.metrics.level_probes += int(probes)
+        # admit fetched entries into the cache (probe_keys is
+        # sorted-unique, so the deduping _cache_update is skipped)
+        if got.any():
+            self._cache_apply(probe_keys[got], gvals[got], fresh=True)
+        return got, gvals, (probes * self.latency.level_ms
+                            + blooms * self.latency.bloom_ms)
 
     def _mem_probe_order(self):
         """Memtable runs in read-priority order: newest delta run first,
@@ -982,47 +1015,49 @@ class LSMStore:
         the equilibrium hit rate rather than a cold-start transient."""
         if len(keys) == 0:
             return
-        cap = self.cache_capacity
-        if len(keys) > cap:
-            rng = rng or np.random.default_rng(0)
-            idx = rng.choice(len(keys), cap, replace=False)
-            keys, vals = keys[idx], vals[idx]
-        # A fresh cache takes the closed-form virgin fill, whose first step
-        # re-sorts the (key-sorted) batch by set.  Fuse both sorts into ONE
-        # argsort of (set << 47) | key — same final (set, key) order, one
-        # mergesort cheaper per prewarm.  Duplicate keys collide in the
-        # packed word exactly when they collide as keys (same key => same
-        # set), so the dedup fallback check carries over.
-        if (self._cache_virgin and len(keys) > 1
-                and self.cache_sets <= (1 << 15)
-                and int(keys.min()) >= 0 and int(keys.max()) < (1 << 47)):
-            sets = self._sets(keys)
-            comb = (sets << np.int64(47)) | keys
-            order = np.argsort(comb, kind="stable")
-            ck = comb[order]
-            if not (ck[1:] == ck[:-1]).any():
-                self._cache_virgin = False
-                self._fill_virgin_sorted(sets[order], keys[order],
-                                         vals[order])
-                self.metrics.reset()
-                return
-        # store-derived keys are unique, so sorting alone reproduces
-        # _cache_update's dedup ordering; fall back to the deduping path
-        # if a caller hands us duplicates
-        order = stable_argsort_keys(keys)
-        sk = keys[order]
-        if len(sk) > 1 and (sk[1:] == sk[:-1]).any():
-            self._cache_update(keys, vals)
-        else:
-            self._cache_apply(sk, vals[order])
-        self.metrics.reset()
+        with span("lsm.prewarm_cache"):
+            cap = self.cache_capacity
+            if len(keys) > cap:
+                rng = rng or np.random.default_rng(0)
+                idx = rng.choice(len(keys), cap, replace=False)
+                keys, vals = keys[idx], vals[idx]
+            # A fresh cache takes the closed-form virgin fill, whose first step
+            # re-sorts the (key-sorted) batch by set.  Fuse both sorts into ONE
+            # argsort of (set << 47) | key — same final (set, key) order, one
+            # mergesort cheaper per prewarm.  Duplicate keys collide in the
+            # packed word exactly when they collide as keys (same key => same
+            # set), so the dedup fallback check carries over.
+            if (self._cache_virgin and len(keys) > 1
+                    and self.cache_sets <= (1 << 15)
+                    and int(keys.min()) >= 0 and int(keys.max()) < (1 << 47)):
+                sets = self._sets(keys)
+                comb = (sets << np.int64(47)) | keys
+                order = np.argsort(comb, kind="stable")
+                ck = comb[order]
+                if not (ck[1:] == ck[:-1]).any():
+                    self._cache_virgin = False
+                    self._fill_virgin_sorted(sets[order], keys[order],
+                                             vals[order])
+                    self.metrics.reset()
+                    return
+            # store-derived keys are unique, so sorting alone reproduces
+            # _cache_update's dedup ordering; fall back to the deduping path
+            # if a caller hands us duplicates
+            order = stable_argsort_keys(keys)
+            sk = keys[order]
+            if len(sk) > 1 and (sk[1:] == sk[:-1]).any():
+                self._cache_update(keys, vals)
+            else:
+                self._cache_apply(sk, vals[order])
+            self.metrics.reset()
 
     # ------------------------------------------------------------- snapshots
     def snapshot(self) -> dict:
         """Epoch-barrier snapshot (Flink-checkpoint analogue).  Carries the
         delta weights so a restore preserves the Z-set, not just the
         last-write-wins view."""
-        keys, weights, vals = self._items_weighted()
+        with span("lsm.snapshot"):
+            keys, weights, vals = self._items_weighted()
         return {"keys": keys, "vals": vals, "weights": weights,
                 "memory_mb": self.memory_mb, "value_words": self.value_words}
 

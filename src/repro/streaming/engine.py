@@ -80,6 +80,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro.obs.spans import span, step
 from repro.streaming.events import EventBatch, hash_partition
 from repro.streaming.graph import Dataflow
 from repro.streaming.operators import (JoinOp, Operator, SessionWindowOp,
@@ -219,61 +220,65 @@ class StreamEngine:
         prewarm over the partition in original arrival order (per-source
         ascending positions, sources in order — the order the lexsort-slice
         path fed the sampler, so the shared rng draws identically)."""
-        from repro.state.lsm import get_store_impl, stable_argsort_keys
-        if get_store_impl() == "legacy":
-            self._install_partitions_lexsort(name, sources)
-            return
-        node = self.flow.nodes[name]
-        p = len(self.tasks[name])
-        assert p <= (1 << 16)    # partition ids must survive the uint16
-        dk = [[] for _ in range(p)]          # key-sorted run fragments
-        dw = [[] for _ in range(p)]
-        dv = [[] for _ in range(p)]
-        ak = [[] for _ in range(p)]          # arrival-order prewarm fragments
-        av = [[] for _ in range(p)]
-        for s in sources:
-            keys = np.asarray(s["keys"], np.int64)
-            if not len(keys):
-                continue
-            vals = np.asarray(s["vals"], np.int32)
-            w = s.get("weights")
-            w = np.ones(len(keys), np.int64) if w is None \
-                else np.asarray(w, np.int64)
-            part = hash_partition(state_partition_keys(node.op, keys), p)
-            # uint16 cast => numpy radix-sorts the partition ids (p < 2^16)
-            order = np.argsort(part.astype(np.uint16), kind="stable")
-            bounds = np.searchsorted(part[order], np.arange(p + 1))
-            for i in range(p):
-                # stable sort on partition only => each slice is already in
-                # original arrival order, so the install fragment doubles as
-                # the prewarm fragment (no second gather)
-                sl = order[bounds[i]:bounds[i + 1]]
-                if not len(sl):
+        with span("engine.install"):
+            from repro.state.lsm import get_store_impl, stable_argsort_keys
+            if get_store_impl() == "legacy":
+                self._install_partitions_lexsort(name, sources)
+                return
+            node = self.flow.nodes[name]
+            p = len(self.tasks[name])
+            assert p <= (1 << 16)    # partition ids must survive the uint16
+            dk = [[] for _ in range(p)]          # key-sorted run fragments
+            dw = [[] for _ in range(p)]
+            dv = [[] for _ in range(p)]
+            ak = [[] for _ in range(p)]      # arrival-order prewarm fragments
+            av = [[] for _ in range(p)]
+            for s in sources:
+                keys = np.asarray(s["keys"], np.int64)
+                if not len(keys):
                     continue
-                kk, vv = keys[sl], vals[sl]
-                dk[i].append(kk)
-                dw[i].append(w[sl])
-                dv[i].append(vv)
-                ak[i].append(kk)
-                av[i].append(vv)
-        for i in range(p):
-            tr = self.tasks[name][i]
-            if dk[i]:
-                if len(dk[i]) == 1:
-                    mk, mw, mv = dk[i][0], dw[i][0], dv[i][0]
-                else:
-                    mk = np.concatenate(dk[i])
-                    mw = np.concatenate(dw[i])
-                    mv = np.concatenate(dv[i])
-                if len(mk) > 1 and (len(dk[i]) > 1
-                                    or np.any(mk[1:] < mk[:-1])):
-                    o = stable_argsort_keys(mk)
-                    mk, mw, mv = mk[o], mw[o], mv[o]
-                tr.state.install_run(mk, mv, mw)
-                wk = ak[i][0] if len(ak[i]) == 1 else np.concatenate(ak[i])
-                wv = av[i][0] if len(av[i]) == 1 else np.concatenate(av[i])
-                tr.state.prewarm_cache(wk, wv, self.rng)
-            tr.state.metrics.reset()
+                vals = np.asarray(s["vals"], np.int32)
+                w = s.get("weights")
+                w = np.ones(len(keys), np.int64) if w is None \
+                    else np.asarray(w, np.int64)
+                with span("engine.partition"):
+                    part = hash_partition(
+                        state_partition_keys(node.op, keys), p)
+                    # uint16 cast => numpy radix-sorts the partition ids
+                    order = np.argsort(part.astype(np.uint16), kind="stable")
+                    bounds = np.searchsorted(part[order], np.arange(p + 1))
+                for i in range(p):
+                    # stable sort on partition only => each slice is
+                    # already in original arrival order, so the install
+                    # fragment doubles as the prewarm fragment (no second
+                    # gather)
+                    sl = order[bounds[i]:bounds[i + 1]]
+                    if not len(sl):
+                        continue
+                    kk, vv = keys[sl], vals[sl]
+                    dk[i].append(kk)
+                    dw[i].append(w[sl])
+                    dv[i].append(vv)
+                    ak[i].append(kk)
+                    av[i].append(vv)
+            for i in range(p):
+                tr = self.tasks[name][i]
+                if dk[i]:
+                    if len(dk[i]) == 1:
+                        mk, mw, mv = dk[i][0], dw[i][0], dv[i][0]
+                    else:
+                        mk = np.concatenate(dk[i])
+                        mw = np.concatenate(dw[i])
+                        mv = np.concatenate(dv[i])
+                    if len(mk) > 1 and (len(dk[i]) > 1
+                                        or np.any(mk[1:] < mk[:-1])):
+                        o = stable_argsort_keys(mk)
+                        mk, mw, mv = mk[o], mw[o], mv[o]
+                    tr.state.install_run(mk, mv, mw)
+                    wk = ak[i][0] if len(ak[i]) == 1 else np.concatenate(ak[i])
+                    wv = av[i][0] if len(av[i]) == 1 else np.concatenate(av[i])
+                    tr.state.prewarm_cache(wk, wv, self.rng)
+                tr.state.metrics.reset()
 
     def _install_partitions_lexsort(self, name: str,
                                     sources: list[dict]) -> None:
@@ -327,18 +332,19 @@ class StreamEngine:
         """Apply C^t: scale out/in re-partitions state; scale up/down resizes
         the state backend (both incur a cold cache — the stabilization period
         the paper describes)."""
-        for name, (p, lvl) in new_config.items():
-            node = self.flow.nodes[name]
-            p_old, lvl_old = node.parallelism, node.memory_level
-            lvl = lvl if node.op.stateful else None
-            if p == p_old and lvl == lvl_old:
-                continue
-            snaps = None
-            if node.op.stateful:
-                snaps = [t.state.snapshot() for t in self.tasks[name]]
-            node.parallelism = p
-            node.memory_level = lvl
-            self._init_op(name, warm=False, snapshots=snaps)
+        with span("engine.reconfigure"):
+            for name, (p, lvl) in new_config.items():
+                node = self.flow.nodes[name]
+                p_old, lvl_old = node.parallelism, node.memory_level
+                lvl = lvl if node.op.stateful else None
+                if p == p_old and lvl == lvl_old:
+                    continue
+                snaps = None
+                if node.op.stateful:
+                    snaps = [t.state.snapshot() for t in self.tasks[name]]
+                node.parallelism = p
+                node.memory_level = lvl
+                self._init_op(name, warm=False, snapshots=snaps)
 
     # ---------------------------------------------------------- fault hooks
     def kill_task(self, name: str, idx: int) -> None:
@@ -370,35 +376,36 @@ class StreamEngine:
     def _emit(self, name: str, out: EventBatch) -> None:
         if len(out) == 0:
             return
-        for d in self._down[name]:
-            dn = self.flow.nodes[d]
-            if dn.op.stateful:
-                part = hash_partition(out.key, dn.parallelism)
-                for i, sl in enumerate(
-                        _partition_groups(part, dn.parallelism)):
-                    if len(sl):
-                        sub = out.select(sl)
-                        t = self.tasks[d][i]
-                        t.queue.append(sub)
-                        self._queued_delta(d, t, len(sub))
-            else:                                   # rebalance round-robin
-                # stable: quicksort's tie order diverges from index order
-                # at >=17 tasks, making the rebalance assignment depend on
-                # sort-algorithm internals instead of task index
-                order = np.argsort([t.queued_events for t in self.tasks[d]],
-                                   kind="stable")
-                # same contiguous ranges np.array_split produces, as views
-                q, r = divmod(len(out), dn.parallelism)
-                lo = 0
-                for j, i in enumerate(order):
-                    hi = lo + q + (1 if j < r else 0)
-                    if hi > lo:
-                        sub = out.slice(lo, hi)
-                        t = self.tasks[d][i]
-                        t.queue.append(sub)
-                        self._queued_delta(d, t, len(sub))
-                    lo = hi
-            self.stats[d].in_events += len(out)
+        with span("engine.emit"):
+            for d in self._down[name]:
+                dn = self.flow.nodes[d]
+                if dn.op.stateful:
+                    part = hash_partition(out.key, dn.parallelism)
+                    for i, sl in enumerate(
+                            _partition_groups(part, dn.parallelism)):
+                        if len(sl):
+                            sub = out.select(sl)
+                            t = self.tasks[d][i]
+                            t.queue.append(sub)
+                            self._queued_delta(d, t, len(sub))
+                else:                                   # rebalance round-robin
+                    # stable: quicksort's tie order diverges from index order
+                    # at >=17 tasks, making the rebalance assignment depend on
+                    # sort-algorithm internals instead of task index
+                    loads = [t.queued_events for t in self.tasks[d]]
+                    order = np.argsort(loads, kind="stable")
+                    # same contiguous ranges np.array_split produces, as views
+                    q, r = divmod(len(out), dn.parallelism)
+                    lo = 0
+                    for j, i in enumerate(order):
+                        hi = lo + q + (1 if j < r else 0)
+                        if hi > lo:
+                            sub = out.slice(lo, hi)
+                            t = self.tasks[d][i]
+                            t.queue.append(sub)
+                            self._queued_delta(d, t, len(sub))
+                        lo = hi
+                self.stats[d].in_events += len(out)
 
     def _downstream_room(self, name: str) -> bool:
         for d in self._down[name]:
@@ -440,72 +447,74 @@ class StreamEngine:
         return d_lat / 1e3
 
     def run_tick(self, target_rate: float) -> None:
-        self.source_target_rate = target_rate
-        for name in self.topo:
-            node = self.flow.nodes[name]
-            op = node.op
-            st = self.stats[name]
-            if isinstance(op, SourceOp):
-                if self._downstream_room(name):
-                    n = int(target_rate * self.tick_s)
-                    out = op.emit(n, self.now)
-                    self.source_emitted += len(out)
-                    st.in_events += len(out)
-                    st.out_events += len(out)
-                    st.processed += len(out)
-                    # source busyness: proportional to emitted volume
-                    per_task = len(out) * op.cpu_cost_us * 1e-6 \
-                        / node.parallelism
-                    for tr in self.tasks[name]:
-                        tr.busy_s += min(per_task, self.tick_s)
-                    self._emit(name, out)
-                else:
-                    st.blocked = True
-                st.task_time_s += self.tick_s * node.parallelism
-                continue
-
-            room = self._downstream_room(name)
-            for idx, tr in enumerate(self.tasks[name]):
-                budget = self.tick_s
-                while budget > 0 and tr.queue and room:
-                    # coalesce queued batches into one vectorized process
-                    # call sized by the task's measured per-event cost.
-                    # Takes are chunk-quantized and never target more than
-                    # a third of the tick, so the tick ends on single-chunk
-                    # takes — reproducing the chunked path's last-chunk
-                    # budget-overshoot profile (which DS2's capacity
-                    # estimate is mildly sensitive to) at a fraction of
-                    # the process-call count.
-                    if tr.cost_per_event is None:    # calibration take
-                        n_take = self.chunk
+        with step("engine.tick", round(self.now / self.tick_s)):
+            self.source_target_rate = target_rate
+            for name in self.topo:
+                node = self.flow.nodes[name]
+                op = node.op
+                st = self.stats[name]
+                if isinstance(op, SourceOp):
+                    if self._downstream_room(name):
+                        n = int(target_rate * self.tick_s)
+                        out = op.emit(n, self.now)
+                        self.source_emitted += len(out)
+                        st.in_events += len(out)
+                        st.out_events += len(out)
+                        st.processed += len(out)
+                        # source busyness: proportional to emitted volume
+                        per_task = len(out) * op.cpu_cost_us * 1e-6 \
+                            / node.parallelism
+                        for tr in self.tasks[name]:
+                            tr.busy_s += min(per_task, self.tick_s)
+                        self._emit(name, out)
                     else:
-                        plan = int(min(budget, self.tick_s / 3)
-                                   / tr.cost_per_event)
-                        n_take = max(self.chunk, plan // self.chunk
-                                     * self.chunk)
-                    batch = self._take(name, tr, n_take)
-                    out = op.process(tr.state, batch)
-                    cost = (len(batch) * op.cpu_cost_us * 1e-6
-                            + self._charge(name, idx))
-                    cost *= tr.slowdown
-                    per = cost / len(batch)
-                    tr.cost_per_event = per if tr.cost_per_event is None \
-                        else 0.5 * tr.cost_per_event + 0.5 * per
-                    budget -= cost
-                    tr.busy_s += cost
-                    tr.processed += len(batch)
-                    st.processed += len(batch)
-                    st.out_events += len(out)
-                    self._emit(name, out)
-                st.busy_s += min(self.tick_s, self.tick_s - budget) \
-                    if budget < self.tick_s else self.tick_s - budget
-                st.task_time_s += self.tick_s
-                if not room:
-                    st.blocked = True
-            # straggler mitigation: re-balance stateless task queues
-            if not op.stateful and node.parallelism > 1:
-                self._rebalance(name)
-        self.now += self.tick_s
+                        st.blocked = True
+                    st.task_time_s += self.tick_s * node.parallelism
+                    continue
+
+                room = self._downstream_room(name)
+                for idx, tr in enumerate(self.tasks[name]):
+                    budget = self.tick_s
+                    while budget > 0 and tr.queue and room:
+                        # coalesce queued batches into one vectorized process
+                        # call sized by the task's measured per-event cost.
+                        # Takes are chunk-quantized and never target more than
+                        # a third of the tick, so the tick ends on single-chunk
+                        # takes — reproducing the chunked path's last-chunk
+                        # budget-overshoot profile (which DS2's capacity
+                        # estimate is mildly sensitive to) at a fraction of
+                        # the process-call count.
+                        if tr.cost_per_event is None:    # calibration take
+                            n_take = self.chunk
+                        else:
+                            plan = int(min(budget, self.tick_s / 3)
+                                       / tr.cost_per_event)
+                            n_take = max(self.chunk, plan // self.chunk
+                                         * self.chunk)
+                        batch = self._take(name, tr, n_take)
+                        with span("engine.process", op=name, task=idx):
+                            out = op.process(tr.state, batch)
+                        cost = (len(batch) * op.cpu_cost_us * 1e-6
+                                + self._charge(name, idx))
+                        cost *= tr.slowdown
+                        per = cost / len(batch)
+                        tr.cost_per_event = per if tr.cost_per_event is None \
+                            else 0.5 * tr.cost_per_event + 0.5 * per
+                        budget -= cost
+                        tr.busy_s += cost
+                        tr.processed += len(batch)
+                        st.processed += len(batch)
+                        st.out_events += len(out)
+                        self._emit(name, out)
+                    st.busy_s += min(self.tick_s, self.tick_s - budget) \
+                        if budget < self.tick_s else self.tick_s - budget
+                    st.task_time_s += self.tick_s
+                    if not room:
+                        st.blocked = True
+                # straggler mitigation: re-balance stateless task queues
+                if not op.stateful and node.parallelism > 1:
+                    self._rebalance(name)
+            self.now += self.tick_s
 
     def _rebalance(self, name: str) -> None:
         tasks = self.tasks[name]

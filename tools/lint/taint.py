@@ -28,8 +28,9 @@ per-file rules use, which lets a single fixture file exercise an
 inherently cross-file property.
 
 **The observability carve-out.**  The obs layer (``src/repro/obs/``) may
-read ``time.perf_counter`` to price its own overhead
-(``Tracer.self_profile``, registry ``Timer``).  That is a *write-only*
+read ``time.perf_counter`` for its registry ``Timer``, the one clock
+read left there (the profiler-clock spans of ``obs/spans.py`` read
+none).  That is a *write-only*
 side channel: a golden function calling ``self.tracer.record(...)`` as a
 bare statement throws the result away, so no clock value can flow back
 into a decision.  T501 therefore refuses to propagate taint across a
