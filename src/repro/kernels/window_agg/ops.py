@@ -6,6 +6,11 @@ the host), ``window_agg.launch`` (the jitted call, which stages the host
 operands and launches the kernel) and ``window_agg.wait`` (copying the
 sums back, which waits for the device), and counts the call and the
 bytes it sends.
+
+The kernel needs ids in ascending order, which the store's calls always
+give (the ranks of key-sorted deltas).  Other ids are first sorted on the
+host, with their values, by one stable argsort; the sums come out per id
+as before.  ``window_agg.remapped`` counts the calls that needed it.
 """
 from __future__ import annotations
 
@@ -25,7 +30,8 @@ def aggregate(seg_ids: np.ndarray, values: np.ndarray, n_segments: int, *,
     the Pallas interpreter) | "ref" (numpy oracle).  The kernel sums the
     values, with events and segments padded up the ``bucket`` ladder
     (padded events carry segment id -1, which matches nothing); counts are
-    a host bincount."""
+    a host bincount.  Ids not in ascending order are sorted first
+    (counter ``window_agg.remapped``)."""
     seg_ids = np.asarray(seg_ids, np.int32)
     values = np.asarray(values, np.float32)
     n, v = values.shape
@@ -40,6 +46,10 @@ def aggregate(seg_ids: np.ndarray, values: np.ndarray, n_segments: int, *,
                                                  window_agg)
     nb, sb = bucket(n, EVENT_TILE), bucket(n_segments, SEG_BLOCK)
     with span("window_agg.prepare"):
+        if np.any(seg_ids[1:] < seg_ids[:-1]):
+            order = np.argsort(seg_ids, kind="stable")
+            seg_ids, values = seg_ids[order], values[order]
+            counts["window_agg.remapped"] += 1
         seg = np.full(nb, -1, np.int32)
         seg[:n] = seg_ids
         rows = np.zeros((v, nb), np.float32)

@@ -18,7 +18,9 @@ call site passes (the keyword arguments of ``span``, the step number of
 ``counts`` holds integer counters that count whether spans are on or
 off.  Each kernel's host wrapper adds, per device call,
 ``<kernel>.calls`` and ``<kernel>.h2d_bytes``, the padded host operands
-it sends to the device.
+it sends to the device.  ``window_agg.remapped`` counts the aggregate
+calls whose ids had to be sorted on the host first (0 on the store's
+path, which sends sorted ranks).
 """
 from __future__ import annotations
 

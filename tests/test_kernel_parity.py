@@ -17,8 +17,11 @@ from repro.kernels.device import bucket
 from repro.kernels.sorted_probe.kernel import (QUERY_BLOCK, TABLE_TILE,
                                                sorted_probe)
 from repro.kernels.sorted_probe.ops import probe, split_keys
-from repro.kernels.window_agg.kernel import EVENT_TILE, SEG_BLOCK, window_agg
+from repro.kernels.window_agg import kernel as agg_kernel
+from repro.kernels.window_agg.kernel import (EVENT_TILE, LANES, SEG_BLOCK,
+                                             window_agg)
 from repro.kernels.window_agg.ops import aggregate
+from repro.obs import spans
 from repro.state import lsm
 from repro.state.lsm import LSMStore
 
@@ -210,6 +213,136 @@ def test_agg_integer_weights_sum_exactly():
     sums, _ = assert_agg_parity(seg, w, 700)
     np.testing.assert_array_equal(
         sums[:, 0], np.bincount(seg, weights=w[:, 0], minlength=700))
+
+
+def dense_ranks(lengths) -> np.ndarray:
+    """Sorted ranks 0, 0, 1, ...: segment i repeated lengths[i] times, the
+    ids the store's consolidation sends."""
+    return np.repeat(np.arange(len(lengths)), lengths).astype(np.int32)
+
+
+def _agg_cases():
+    rng = np.random.default_rng(21)
+    straddle = rng.integers(1, 700, 70)            # ~24K events
+    return {
+        # one segment per event, shifted so a tile starts mid-block and
+        # spans EVENT_TILE // SEG_BLOCK + 1 blocks
+        "one_per_event": (dense_ranks([100] + [1] * (2 * EVENT_TILE)), 1),
+        "one_segment_many_tiles": (dense_ranks([3 * EVENT_TILE + 5]), 1),
+        "straddling_tiles_and_blocks": (dense_ranks(straddle), 1),
+        # bucket(n) = 4 tiles: the last tile is all padding
+        "just_over_a_bucket_edge": (dense_ranks([1] * (2 * EVENT_TILE + 1)),
+                                    1),
+        "several_value_rows": (dense_ranks(rng.integers(1, 40, 900)), 3),
+    }
+
+
+@pytest.mark.parametrize("case", list(_agg_cases()))
+def test_agg_dense_sorted_ranks_match_ref(case):
+    """The store's input, dense sorted ranks, goes to the banded kernel
+    as it is and sums exactly."""
+    seg, v = _agg_cases()[case]
+    n_segments = int(seg[-1]) + 1
+    vals = np.random.default_rng(3).integers(0, 9, (len(seg), v)) \
+        .astype(np.float32)
+    before = spans.counts["window_agg.remapped"]
+    s1, c1 = aggregate(seg, vals, n_segments, impl="interpret")
+    s2, c2 = aggregate(seg, vals, n_segments, impl="ref")
+    np.testing.assert_array_equal(s1, s2)
+    np.testing.assert_array_equal(c1, c2)
+    assert spans.counts["window_agg.remapped"] == before
+
+
+def test_agg_integer_sums_exact_near_2_24():
+    """Segment sums up to 2^24 - 1 stay exact in float32: no partial sum
+    is ever larger than its segment's total."""
+    w = np.concatenate([np.full(8191, 2048), [2047],        # 2^24 - 1
+                        np.full(4096, 4095), [4095],        # 2^24 - 4095
+                        np.arange(1, 9)]).astype(np.float32)
+    seg = dense_ranks([8192, 4097] + [1] * 8)
+    sums, _ = aggregate(seg, w[:, None], 10, impl="interpret")
+    want = np.bincount(seg, weights=w.astype(np.int64), minlength=10)
+    assert want[0] == 2**24 - 1
+    np.testing.assert_array_equal(sums[:, 0].astype(np.int64), want)
+
+
+@pytest.mark.parametrize("lengths", [[1] * (3 * EVENT_TILE),
+                                     [100] + [1] * (2 * EVENT_TILE),
+                                     [5000, 1, 1, 3 * EVENT_TILE, 7, 600]])
+def test_agg_schedule_compares_each_row_with_only_its_blocks(lengths):
+    """The merge path has tiles + blocks - 1 steps, visits each segment
+    block in one run, and compares every event row with exactly the
+    blocks its ids fall in: one row compare per (row, block) pair."""
+    import jax.numpy as jnp
+    seg = dense_ranks(lengths)
+    nb = bucket(len(seg), EVENT_TILE)
+    n_blocks = bucket(int(seg[-1]) + 1, SEG_BLOCK) // SEG_BLOCK
+    padded = np.full(nb, -1, np.int32)
+    padded[:len(seg)] = seg
+    sched = np.asarray(agg_kernel._schedule(jnp.asarray(padded), n_blocks))
+    tile, r0, r1 = (np.asarray(a) for a in agg_kernel._unpack(sched))
+    block = np.arange(len(sched)) - tile
+    assert len(sched) == nb // EVENT_TILE + n_blocks - 1
+    assert (np.diff(tile) >= 0).all() and (np.diff(block) >= 0).all()
+    assert set(block) == set(range(n_blocks))
+    compared = {(t * agg_kernel.ROWS + r, b)
+                for t, lo, hi, b in zip(tile, r0, r1, block)
+                for r in range(lo, hi)}
+    rows = np.arange(len(seg)) // LANES
+    needed = set(zip(rows.tolist(), (seg // SEG_BLOCK).tolist()))
+    assert compared == needed
+    assert (r1 - r0).sum() == len(needed)
+
+
+def test_agg_unsorted_ids_are_sorted_first_and_counted():
+    rng = np.random.default_rng(4)
+    seg = rng.integers(0, 2000, 9000).astype(np.int32)
+    vals = rng.integers(0, 9, (9000, 2)).astype(np.float32)
+    before = spans.counts["window_agg.remapped"]
+    s1, c1 = aggregate(seg, vals, 2000, impl="interpret")
+    assert spans.counts["window_agg.remapped"] == before + 1
+    s2, c2 = aggregate(seg, vals, 2000, impl="ref")
+    np.testing.assert_array_equal(s1, s2)
+    np.testing.assert_array_equal(c1, c2)
+
+
+def test_agg_sorted_ids_with_gaps_match_ref():
+    """Sorted ids that skip segments (empty blocks, a row spanning many
+    blocks) need no sorting and still sum exactly."""
+    rng = np.random.default_rng(9)
+    seg = np.sort(np.concatenate([rng.integers(0, 60_000, 6000),
+                                  np.arange(0, 60_000, 997)])).astype(np.int32)
+    vals = rng.integers(0, 9, (len(seg), 1)).astype(np.float32)
+    before = spans.counts["window_agg.remapped"]
+    sums, _ = assert_agg_parity(seg, vals, 60_000)
+    assert spans.counts["window_agg.remapped"] == before
+    np.testing.assert_array_equal(
+        sums[:, 0], np.bincount(seg, weights=vals[:, 0], minlength=60_000))
+
+
+def test_store_sends_only_sorted_ranks(monkeypatch):
+    """Consolidation, flush and snapshot on the kernel path call the
+    kernel with sorted ranks: nothing is sorted again on the host."""
+    calls = {"consolidate": 0}
+    consolidate = LSMStore._consolidate
+
+    def counted(self):
+        calls["consolidate"] += 1
+        consolidate(self)
+
+    monkeypatch.setattr(LSMStore, "_consolidate", counted)
+    rng = np.random.default_rng(13)
+    store = LSMStore(0.5, value_words=2, kernel_impl="interpret")
+    before = spans.counts.copy()
+    for _ in range(40):                      # 8 runs consolidate, then flush
+        keys = rng.integers(0, 3_000, 10).astype(np.int64)
+        store.put_batch(keys, rng.integers(0, 99, (10, 2)).astype(np.int32))
+    snap = store.snapshot()
+    got = spans.counts - before
+    assert calls["consolidate"] > 0 and store.metrics.flushes > 0
+    assert len(snap["keys"]) > 0
+    assert got["window_agg.calls"] > 0
+    assert got["window_agg.remapped"] == 0
 
 
 # ------------------------------------------ LSM store dispatch: kernel path
