@@ -20,7 +20,9 @@ off.  Each kernel's host wrapper adds, per device call,
 ``<kernel>.calls`` and ``<kernel>.h2d_bytes``, the padded host operands
 it sends to the device.  ``window_agg.remapped`` counts the aggregate
 calls whose ids had to be sorted on the host first (0 on the store's
-path, which sends sorted ranks).
+path, which sends sorted ranks).  The hopping-window count adds
+``hop.updates``, the unique (key, window) pairs it writes, and
+``hop.fired``, the windows whose hot item it emitted.
 """
 from __future__ import annotations
 
@@ -38,6 +40,7 @@ SPANS = (
     "sorted_probe.prepare", "sorted_probe.launch", "sorted_probe.wait",
     "window_agg.prepare", "window_agg.launch", "window_agg.wait",
     "kernel.first_call",
+    "hop.assign", "hop.combine", "hop.fire", "hop.expire",
 )
 
 counts: collections.Counter = collections.Counter()

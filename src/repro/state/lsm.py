@@ -485,6 +485,31 @@ class LSMStore:
                 weights = np.ones(len(keys), np.int64)
             self._push_run(keys, weights, vals)
 
+    def purge(self, keep) -> None:
+        """Delete every entry whose key ``keep`` (a keys -> bool mask
+        function) rejects, from every memtable run, tier and level and
+        from the cache, at once: a window's state cleared when the
+        window ends.  A ``compact_filter`` drops only from the run being
+        flushed or merged, so an older version in another level would
+        be read again; this leaves none.  The dropped weight counts as
+        annihilated; the flush cadence (``mem_n``) is unchanged."""
+        def cut(runs):
+            out = []
+            for k, w, v in runs:
+                m = keep(k)
+                if not m.all():
+                    self.annihilated += int(w[~m].sum())
+                    k, w, v = k[m], w[m], v[m]
+                if len(k):
+                    out.append((k, w, v))
+            return out
+        self._runs = cut(self._runs)
+        self._tiers = cut(self._tiers)
+        self.levels = cut(self.levels)
+        gone = (self.cache_keys >= 0) & ~keep(self.cache_keys)
+        self.cache_keys[gone] = -1
+        self.cache_ref[gone] = 0
+
     def _flush(self) -> None:
         if self.mem_n == 0:
             return

@@ -17,7 +17,10 @@ Mechanics faithful to Flink/the paper:
   * reconfiguration with state re-partitioning (scale out/in) and state
     backend resize (scale up/down),
   * straggler mitigation: queue re-balancing for stateless tasks; slowdown
-    injection for tests.
+    injection for tests,
+  * event-time watermarks for operators that ask for them: a task's is
+    the least event time every upstream task has finished, held back by
+    its own queue (``_advance``).
 
 Fast-path invariants (the coalesced processing path MUST preserve these —
 they are what the golden-trace regression test pins down):
@@ -83,8 +86,8 @@ import numpy as np
 from repro.obs.spans import span, step
 from repro.streaming.events import EventBatch, hash_partition
 from repro.streaming.graph import Dataflow
-from repro.streaming.operators import (JoinOp, Operator, SessionWindowOp,
-                                       SinkOp, SourceOp, WindowAggOp)
+from repro.streaming.operators import (WINDOW_BITS, JoinOp, Operator,
+                                       SourceOp, WindowAggOp)
 
 BASE_MEM_MB = 158.0                  # default managed memory per slot (§5)
 
@@ -95,9 +98,12 @@ def level_mb(level: int | None, base_mb: float = BASE_MEM_MB) -> float:
 
 
 def state_partition_keys(op: Operator, state_keys: np.ndarray) -> np.ndarray:
-    """Recover the event key a state entry belongs to (for re-partitioning)."""
+    """Recover the event key a state entry belongs to (for re-partitioning):
+    the key above a window id for ``WindowAggOp`` and the tumbling join;
+    every other operator (``HotItemsOp``: the window id) is keyed by the
+    state key itself."""
     if isinstance(op, WindowAggOp):
-        return state_keys // np.int64(1 << 20)
+        return state_keys >> np.int64(WINDOW_BITS)
     if isinstance(op, JoinOp):
         k = state_keys
         if op.window_s is not None:
@@ -166,6 +172,19 @@ class StreamEngine:
         self._over: dict[str, int] = {}   # tasks per op with queue over cap
         self.source_emitted = 0
         self.source_target_rate = 0.0
+        # event-time progress, kept only where an event-time operator
+        # reads it: for each operator upstream of one (and for each such
+        # operator), the lowest timestamp it may still process or emit
+        self._low: dict[str, float] = {}
+        self._up: dict[str, list[str]] = {}
+        for name in self.topo:
+            if flow.nodes[name].op.event_time:
+                todo = [name]
+                while todo:
+                    n = todo.pop()
+                    if n not in self._up:
+                        self._up[n] = flow.upstream(n)
+                        todo.extend(self._up[n])
         for name in self.topo:
             self._init_op(name, warm=warm)
 
@@ -470,6 +489,8 @@ class StreamEngine:
                     else:
                         st.blocked = True
                     st.task_time_s += self.tick_s * node.parallelism
+                    if name in self._up:     # next tick's events come later
+                        self._low[name] = self.now + self.tick_s
                     continue
 
                 room = self._downstream_room(name)
@@ -514,7 +535,29 @@ class StreamEngine:
                 # straggler mitigation: re-balance stateless task queues
                 if not op.stateful and node.parallelism > 1:
                     self._rebalance(name)
+                if name in self._up:
+                    self._advance(name)
             self.now += self.tick_s
+
+    def _advance(self, name: str) -> None:
+        """Event-time progress after ``name``'s turn in a tick (Flink's
+        watermark rule): what reaches a task comes from every upstream
+        task, so its input watermark is the least of the upstream
+        operators' low marks; its own watermark also waits for the
+        events still in its queue.  An event-time operator's tasks get
+        theirs through ``on_watermark``; the operator's low mark is the
+        least over its tasks."""
+        op = self.flow.nodes[name].op
+        w_in = min((self._low[u] for u in self._up[name]), default=np.inf)
+        low = w_in
+        for tr in self.tasks[name]:
+            wm = min([w_in] + [float(b.ts.min()) for b in tr.queue])
+            low = min(low, wm)
+            if op.event_time:
+                out = op.on_watermark(tr.state, wm)
+                self.stats[name].out_events += len(out)
+                self._emit(name, out)
+        self._low[name] = low
 
     def _rebalance(self, name: str) -> None:
         tasks = self.tasks[name]

@@ -6,7 +6,8 @@ microbenchmarks characterize:
 * ``KeyedStateOp(mode="read")``   — pure lookups (Read workload)
 * ``KeyedStateOp(mode="write")``  — blind writes (Write workload)
 * ``KeyedStateOp(mode="update")`` — read-modify-write (Update workload)
-* ``WindowAggOp`` / ``SessionWindowOp`` / ``JoinOp`` — the Nexmark patterns.
+* ``WindowAggOp`` + ``HotItemsOp`` / ``SessionWindowOp`` / ``JoinOp`` — the
+  Nexmark patterns.
 """
 from __future__ import annotations
 
@@ -15,6 +16,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro.obs.spans import counts, span
 from repro.state.lsm import LSMStore, LatencyModel, make_store
 from repro.streaming.events import EventBatch, PAYLOAD_WORDS
 
@@ -24,6 +26,7 @@ class Operator:
     stateful = False
     cpu_cost_us = 1.0                   # per-event CPU service time component
     entry_bytes = 1000                  # logical state-entry size (§3: 1 KB)
+    event_time = False                  # True: gets ``on_watermark`` calls
 
     def __init__(self, name: str):
         self.name = name
@@ -38,6 +41,13 @@ class Operator:
 
     def process(self, state: LSMStore | None, batch: EventBatch) -> EventBatch:
         raise NotImplementedError
+
+    def on_watermark(self, state: LSMStore | None,
+                     watermark: float) -> EventBatch:
+        """Every event with a timestamp below ``watermark`` that this
+        task will receive has been processed (the engine calls this for
+        each task after its turn in a tick): fire what that completes."""
+        return EventBatch.empty()
 
     def warm_state(self, state: LSMStore, rng: np.random.Generator) -> None:
         """Optional pre-population (paper §3 pre-populates every key)."""
@@ -147,53 +157,150 @@ class KeyedStateOp(Operator):
         return EventBatch(batch.key, new, batch.ts, batch.kind)
 
 
-class WindowAggOp(Operator):
-    """Keyed tumbling/sliding window aggregation (count/sum).
+WINDOW_BITS = 20                        # window state key: key << 20 | window
+WINDOW_MASK = np.int64((1 << WINDOW_BITS) - 1)
+KEY_LIMIT = np.int64(1) << np.int64(63 - WINDOW_BITS)
 
-    State key = (key, window_id); each event is a read-modify-write.  Sliding
-    windows touch size/slide window ids per event — q5's 'complex access
-    pattern'.  Window results are emitted when event time passes window end.
-    """
+
+def window_key(keys: np.ndarray, wid: np.ndarray) -> np.ndarray:
+    """State key of (event key, window id): the event key above the low
+    ``WINDOW_BITS`` bits, the window id modulo ``2**WINDOW_BITS`` in
+    them."""
+    keys = np.asarray(keys, np.int64)
+    if len(keys) and (keys.min() < 0 or keys.max() >= KEY_LIMIT):
+        raise ValueError("event key outside [0, 2**43)")
+    return (keys << WINDOW_BITS) | (wid & WINDOW_MASK)
+
+
+class WindowAggOp(Operator):
+    """Hopping-window count per key (NEXmark q5, Flink's
+    ``HOP(size, slide)``), with a combiner for the per-window maximum.
+
+    Windows are ``[k*slide, k*slide + size)``; a window's id is the index
+    of its end in slides (``k + size/slide``), so an event at ``ts`` lies
+    in the ``size/slide`` windows with ids ``floor(ts/slide) + 1`` to
+    ``floor(ts/slide) + size/slide``.  State key = (key, window id)
+    (``window_key``), word 0 of the value the window's count.  A batch
+    is counted with one ``np.unique`` over its (key, window) pairs, then
+    read and written back in one ``get_batch`` and one ``put_batch``.
+
+    Output (the combiner): for each window the batch touched, the keys
+    whose new count equals the batch's largest for that window, as rows
+    keyed by window id with value ``[key, count, 0, 0]``, the batch's
+    earliest timestamp and kind 0.  Counts only grow and a key reaches
+    each count once, so the keys at a window's final maximum are all
+    among these rows, each once: ``HotItemsOp`` finds the maximum, its
+    lowest key and the number of keys at it from them exactly.
+
+    Event time: when the engine's watermark passes a window's end, the
+    window's counts are deleted (``LSMStore.purge``)."""
     stateful = True
+    event_time = True
     cpu_cost_us = 2.5
     entry_bytes = 500                    # window aggregates are small records
 
-    def __init__(self, name: str, size_s: float, slide_s: float | None = None,
-                 emit: bool = True):
+    def __init__(self, name: str, size_s: float, slide_s: float):
         super().__init__(name)
+        n = size_s / slide_s
+        if n != round(n) or n < 1:
+            raise ValueError(f"window size {size_s} s is not a multiple "
+                             f"of its slide {slide_s} s")
         self.size_s = size_s
-        self.slide_s = slide_s or size_s
-        self.emit = emit
-        self._watermark = 0.0
-
-    def _state_key(self, keys, window_id):
-        return keys * np.int64(1 << 20) + (window_id % (1 << 20))
+        self.slide_s = slide_s
+        self.windows_per_event = int(round(n))
 
     def process(self, state: LSMStore, batch: EventBatch) -> EventBatch:
         if len(batch) == 0:
             return EventBatch.empty()
-        # compaction filter: drop windows older than the retention horizon
-        if len(batch):
-            wm = int(batch.ts.max() // self.size_s)
-            state.compact_filter = \
-                lambda keys, w=wm: (keys % (1 << 20)) >= max(0, w - 4)
-        n_windows = max(1, int(round(self.size_s / self.slide_s)))
-        outs = []
-        for w in range(n_windows):
-            wid = ((batch.ts - w * self.slide_s) // self.size_s).astype(np.int64)
-            sk = self._state_key(batch.key, wid)
-            vals, _ = state.get_batch(sk)
-            vals[:, 0] += 1                             # count
-            vals[:, 1] = (vals[:, 1] + batch.value[:, 0]).astype(np.int32)
-            state.put_batch(sk, vals)
-            if w == 0:
-                outs.append(EventBatch(batch.key, vals, batch.ts, batch.kind))
-        self._watermark = max(self._watermark, float(batch.ts.max()))
-        out = outs[0]
-        if not self.emit:
+        with span("hop.assign"):
+            first = np.floor(batch.ts / self.slide_s).astype(np.int64) + 1
+            wid = first[:, None] + np.arange(self.windows_per_event)
+            uq, cnt = np.unique(window_key(batch.key[:, None], wid),
+                                return_counts=True)
+        vals, _ = state.get_batch(uq, uhint=(uq, np.ones_like(cnt)))
+        vals[:, 0] += cnt.astype(np.int32)
+        state.put_batch(uq, vals)
+        counts["hop.updates"] += len(uq)
+        with span("hop.combine"):
+            win = uq & WINDOW_MASK
+            c = vals[:, 0]
+            lo = win.min()
+            best = np.zeros(int(win.max() - lo) + 1, np.int32)
+            np.maximum.at(best, win - lo, c)
+            top = np.flatnonzero(c == best[win - lo])
+            value = np.zeros((len(top), vals.shape[1]), np.int32)
+            value[:, 0] = uq[top] >> WINDOW_BITS
+            value[:, 1] = c[top]
+            return EventBatch(win[top], value,
+                              np.full(len(top), batch.ts.min()),
+                              np.zeros(len(top), np.int8))
+
+    def on_watermark(self, state: LSMStore, watermark: float) -> EventBatch:
+        with span("hop.expire"):
+            closed = int(np.floor(watermark / self.slide_s))
+            state.purge(lambda keys: (keys & WINDOW_MASK) > closed)
+        return EventBatch.empty()
+
+
+class HotItemsOp(Operator):
+    """The hot item of each window (NEXmark q5's second stage), from the
+    combiner rows of ``WindowAggOp``: the largest count, the lowest key
+    at it and the number of keys at it.
+
+    Keyed by window id, so any parallelism is exact.  State per window:
+    ``[max count, lowest key at max, keys at max, 0]``.  When the
+    engine's watermark passes a window's end (id x ``slide_s``), its row
+    ``[key, count, ties, 0]`` is emitted, keyed by window id and stamped
+    with the window's end (kind 0), and its state deleted.  ``q5.sql``
+    emits one row per tied key; here ties are the count beside the
+    lowest key."""
+    stateful = True
+    event_time = True
+    cpu_cost_us = 1.0
+    entry_bytes = 500
+
+    def __init__(self, name: str, slide_s: float):
+        super().__init__(name)
+        self.slide_s = slide_s
+
+    def process(self, state: LSMStore, batch: EventBatch) -> EventBatch:
+        if len(batch) == 0:
             return EventBatch.empty()
-        # emit current aggregates for closed-ish windows (downstream load)
-        return out
+        with span("hop.fire"):
+            key = batch.value[:, 0].astype(np.int64)
+            cnt = batch.value[:, 1]
+            order = np.lexsort((key, -cnt.astype(np.int64), batch.key))
+            win, key, cnt = batch.key[order], key[order], cnt[order]
+            starts = np.flatnonzero(np.r_[True, win[1:] != win[:-1]])
+            m, low = cnt[starts], key[starts]
+            at_max = cnt == np.repeat(m, np.diff(np.r_[starts, len(win)]))
+            ties = np.add.reduceat(at_max.astype(np.int32), starts)
+            uw = win[starts]
+            vals, found = state.get_batch(uw)
+            old_m, old_low, old_ties = vals[:, 0], vals[:, 1], vals[:, 2]
+            up = ~found | (m > old_m)
+            eq = found & (m == old_m)
+            vals[:, 0] = np.where(up, m, old_m)
+            vals[:, 1] = np.where(up, low, np.where(
+                eq, np.minimum(low, old_low), old_low))
+            vals[:, 2] = np.where(up, ties, old_ties + np.where(eq, ties, 0))
+            state.put_batch(uw, vals)
+        return EventBatch.empty()
+
+    def on_watermark(self, state: LSMStore, watermark: float) -> EventBatch:
+        with span("hop.fire"):
+            closed = int(np.floor(watermark / self.slide_s))
+            keys, vals = state.items()
+            due = keys <= closed
+            counts["hop.fired"] += int(due.sum())
+            if not due.any():
+                return EventBatch.empty()
+            state.purge(lambda k: k > closed)
+            value = np.zeros_like(vals[due])
+            value[:, 0], value[:, 1], value[:, 2] = \
+                vals[due, 1], vals[due, 0], vals[due, 2]
+            return EventBatch(keys[due], value, keys[due] * self.slide_s,
+                              np.zeros(len(value), np.int8))
 
 
 class SessionWindowOp(Operator):

@@ -112,6 +112,31 @@ def test_compact_filter_drops_entries(rng):
     assert (keys >= 500).all()
 
 
+@pytest.mark.parametrize("impl", ["numpy", "interpret"])
+def test_purge_leaves_no_older_version(rng, impl):
+    """Keys written to a level, then again to memtable runs and read
+    into the cache: ``purge`` removes every version of the rejected keys
+    at once (a compaction filter on the next flush would drop only the
+    newest, and the level's version would be read again)."""
+    s = LSMStore(0.5, value_words=2, kernel_impl=impl)
+    keys = np.arange(300, dtype=np.int64)
+    s.put_batch(keys, np.ones((300, 2), np.int32))
+    s._flush()
+    for i in range(3):                          # delta runs over the level
+        s.put_batch(keys[::2], np.full((150, 2), 2 + i, np.int32))
+    s.get_batch(keys)                           # level reads fill the cache
+    w0 = s.total_weight()
+    s.purge(lambda k: k >= 100)
+    vals, found = s.get_batch(keys)
+    assert not found[:100].any() and found[100:].all()
+    np.testing.assert_array_equal(vals[100:, 0],
+                                  np.where(keys[100:] % 2, 1, 4))
+    got, _ = s.items()
+    np.testing.assert_array_equal(got, keys[100:])
+    assert s.annihilated == w0 - s.total_weight() == 100 + 3 * 50
+    assert not np.isin(s.cache_keys, keys[:100]).any()
+
+
 def test_cache_hit_rate_increases_with_memory(rng):
     """Takeaway 2: bigger cache => higher read hit rate (uniform reads)."""
     rates = []

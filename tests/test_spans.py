@@ -51,8 +51,9 @@ def _store_work():
 
 
 def _engine_work():
-    """A keyed-state operator ticked, rescaled and ticked again."""
-    from repro.data.nexmark import BidGen
+    """A keyed-state operator ticked, rescaled and ticked again; then q5
+    until its first windows close."""
+    from repro.data.nexmark import QUERIES, BidGen
     from repro.streaming.engine import StreamEngine
     from repro.streaming.graph import Dataflow
     from repro.streaming.operators import KeyedStateOp, SinkOp, SourceOp
@@ -65,6 +66,7 @@ def _engine_work():
     eng.run(3, 5_000)
     eng.reconfigure({"agg": (3, 1)})
     eng.run(2, 5_000)
+    StreamEngine(QUERIES["q5"](), seed=0).run(3, 2_000)
 
 
 def _host_events(log_dir: str) -> list[tuple]:
@@ -147,7 +149,8 @@ def test_kernel_spans_nest_inside_the_store_spans(traced):
 def test_engine_spans_carry_the_tick_and_the_task(traced):
     events, _ = traced
     ticks = _named(events, "engine.tick")
-    assert [t[3]["step_num"] for t in ticks] == [0, 1, 2, 3, 4]
+    assert [t[3]["step_num"] for t in ticks] == [0, 1, 2, 3, 4,  # agg
+                                                 0, 1, 2]        # q5
     procs = _named(events, "engine.process")
     assert procs and all(_inside(p, ticks) for p in procs)
     assert {(p[3]["op"], p[3]["task"]) for p in procs} >= {
