@@ -18,9 +18,12 @@ call site passes (the keyword arguments of ``span``, the step number of
 ``counts`` holds integer counters that count whether spans are on or
 off.  Each kernel's host wrapper adds, per device call,
 ``<kernel>.calls`` and ``<kernel>.h2d_bytes``, the padded host operands
-it sends to the device.  ``window_agg.remapped`` counts the aggregate
-calls whose ids had to be sorted on the host first (0 on the store's
-path, which sends sorted ranks).  The hopping-window count adds
+it sends to the device.  The probe sends a table once:
+``sorted_probe.table_uploads`` counts tables sent (their bytes go to
+``h2d_bytes`` then) and ``sorted_probe.table_reuses`` the calls whose
+table was already on the device.  ``window_agg.remapped`` counts the
+aggregate calls whose ids had to be sorted on the host first (0 on the
+store's path, which sends sorted ranks).  The hopping-window count adds
 ``hop.updates``, the unique (key, window) pairs it writes, and
 ``hop.fired``, the windows whose hot item it emitted.
 """

@@ -30,9 +30,10 @@ the default and the oracle).  ``kernel_impl="pallas"`` routes probes and
 segment sums through the compiled ``repro.kernels`` on a TPU and refuses
 to run anywhere else; ``"interpret"`` runs the same kernels in the Pallas
 interpreter, which is how CPU tests cover them.  Keys reach the device as
-two int32 words each (lossless over int64).  Weight sums on the device
-are float32 — exact below 2^24, far above any per-flush occurrence
-count.
+two int32 words each (lossless over int64); a run's key words go once,
+at its first probe, and stay while the store holds the run.  Weight sums
+on the device are float32 — exact below 2^24, far above any per-flush
+occurrence count.
 
 Byte accounting uses the paper's *logical* entry size (1000 B values, as
 in the §3 microbenchmarks) while physical storage keeps ``value_words``
@@ -234,6 +235,7 @@ class LSMStore:
         self.kernel_impl = kernel_impl or DEFAULT_KERNEL_IMPL
         _check_kernel_impl(self.kernel_impl)
         self.annihilated = 0          # weight dropped by compaction filters
+        self._resident = None         # device words of probed runs
         self._configure_memory(memory_mb)
         # sorted-unique (keys, weights, vals) runs, newest first
         self.levels: list[tuple[np.ndarray, np.ndarray, np.ndarray]] = []
@@ -328,13 +330,27 @@ class LSMStore:
         with span("lsm.probe"):
             if self.kernel_impl != "numpy":
                 from repro.kernels.sorted_probe.ops import probe
-                pos, hit = probe(run_keys, queries, impl=self.kernel_impl)
+                pos, hit = probe(self._device_keys(run_keys), queries,
+                                 impl=self.kernel_impl)
                 return np.minimum(pos.astype(np.int64),
                                   max(len(run_keys) - 1, 0)), hit
             pos = np.searchsorted(run_keys, queries)
             pos_c = np.minimum(pos, len(run_keys) - 1)
             hit = (run_keys[pos_c] == queries) & (pos < len(run_keys))
             return pos_c, hit
+
+    def _device_keys(self, run_keys: np.ndarray):
+        """``run_keys``' padded words on the device: uploaded at the run's
+        first probe (never inside a flush, merge or install) and kept
+        while the store holds the run, so every later probe sends only
+        its queries.  Runs are never changed once built; an uploaded run's
+        keys are made read-only all the same."""
+        if self._resident is None:
+            from repro.kernels.sorted_probe.ops import ResidentTables
+            self._resident = ResidentTables()
+        return self._resident.get(run_keys, live=(
+            r[0] for runs in (self._runs, self._tiers, self.levels)
+            for r in runs))
 
     def _segment_sum(self, sorted_w: np.ndarray, starts: np.ndarray,
                      first_mask: np.ndarray) -> np.ndarray:

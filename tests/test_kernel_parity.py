@@ -392,3 +392,167 @@ def test_pallas_impl_refuses_to_run_off_the_tpu():
     assert lsm.DEFAULT_KERNEL_IMPL == "numpy"
     with pytest.raises(ValueError):
         LSMStore(0.5, kernel_impl="cuda")
+
+
+# ------------------------------ LSM store dispatch: device-resident run keys
+def _live_runs(store) -> list:
+    return [r[0] for runs in (store._runs, store._tiers, store.levels)
+            for r in runs]
+
+
+def assert_only_live_runs_resident(store) -> None:
+    res = store._resident
+    if res is not None:
+        assert len(res) == sum(k in res for k in _live_runs(store))
+
+
+def _purge(store) -> LSMStore:
+    store.purge(lambda k: k % 3 != 0)
+    return store
+
+
+def _resize(store) -> LSMStore:
+    store.resize(0.25)
+    return store
+
+
+def _restore(store) -> LSMStore:
+    return LSMStore.restore(store.snapshot(), kernel_impl=store.kernel_impl)
+
+
+def _flush(store) -> LSMStore:
+    store._flush()
+    return store
+
+
+def _install(store) -> LSMStore:
+    """A large installed run, which compacts the levels above it."""
+    store.install_run(np.arange(5_000, dtype=np.int64) * 5,
+                      np.zeros((5_000, 2), np.int32))
+    return store
+
+
+@pytest.mark.parametrize("change", [_flush, _purge, _resize, _restore],
+                         ids=["flush_merge", "purge", "resize", "restore"])
+def test_resident_run_keys_match_the_numpy_store(change):
+    """Reads through device-resident run keys answer exactly as the numpy
+    store does across flushes, level merges and ``change``, and the
+    resident map never holds a run the store has dropped."""
+    rng = np.random.default_rng(17)
+    a = LSMStore(0.5, value_words=2, kernel_impl="numpy")
+    b = LSMStore(0.5, value_words=2, kernel_impl="interpret")
+    before = spans.counts.copy()
+    written = np.empty(0, np.int64)
+    for step in range(8):
+        n = int(rng.integers(50, 600))
+        keys = rng.integers(0, 60_000, n).astype(np.int64)
+        vals = rng.integers(0, 1 << 30, (n, 2)).astype(np.int32)
+        a.put_batch(keys, vals)
+        b.put_batch(keys, vals)
+        written = np.r_[written, keys]
+        if step == 4:
+            a, b = change(a), change(b)
+        for _ in range(2):                  # the second read reuses runs
+            q = np.r_[rng.choice(written, 200),
+                      rng.integers(0, 61_000, 100)].astype(np.int64)
+            ga, fa = a.get_batch(q)
+            gb, fb = b.get_batch(q)
+            np.testing.assert_array_equal(fa, fb, err_msg=str(step))
+            np.testing.assert_array_equal(ga, gb, err_msg=str(step))
+        assert a.metrics.snapshot() == b.metrics.snapshot(), step
+        assert_only_live_runs_resident(b)
+    got = spans.counts - before
+    assert b.metrics.flushes > 0 and b.metrics.compactions > 0
+    assert got["sorted_probe.table_reuses"] > 0
+    assert got["sorted_probe.table_uploads"] \
+        + got["sorted_probe.table_reuses"] == got["sorted_probe.calls"]
+    np.testing.assert_array_equal(a.items()[0], b.items()[0])
+    np.testing.assert_array_equal(a.items()[1], b.items()[1])
+
+
+@pytest.mark.parametrize("k", [1, 2, 5])
+def test_a_run_probed_k_times_is_uploaded_once(k):
+    rng = np.random.default_rng(k)
+    store = LSMStore(0.5, value_words=2, kernel_impl="interpret")
+    keys = np.sort(rng.choice(1 << 40, 9_000, replace=False))
+    store.install_run(keys, np.zeros((len(keys), 2), np.int32))
+    run = store.levels[0][0]
+    before = spans.counts.copy()
+    for _ in range(k):
+        pos, hit = store._probe_run(run, keys[::7])
+        assert hit.all() and (run[pos] == keys[::7]).all()
+    got = spans.counts - before
+    tp, qp = bucket(len(keys), TABLE_TILE), bucket(len(keys[::7]),
+                                                   QUERY_BLOCK)
+    assert got["sorted_probe.table_uploads"] == 1
+    assert got["sorted_probe.table_reuses"] == k - 1
+    assert got["sorted_probe.h2d_bytes"] == 8 * (tp + k * qp)
+
+
+def _probe_every_run(store) -> None:
+    for run in _live_runs(store):
+        store._probe_run(run, run[:3])
+
+
+@pytest.mark.parametrize("drop", [_install, _purge, _flush],
+                         ids=["compaction", "purge", "flush"])
+def test_resident_map_holds_only_the_live_runs(drop):
+    rng = np.random.default_rng(19)
+    store = LSMStore(0.5, value_words=2, kernel_impl="interpret")
+    for _ in range(12):
+        keys = rng.integers(0, 2_000, 40).astype(np.int64)
+        store.put_batch(keys, np.ones((40, 2), np.int32))
+    assert store.levels and store._runs
+    _probe_every_run(store)
+    n_before = len(store._resident)
+    assert n_before == len(_live_runs(store))
+    drop(store)
+    assert_only_live_runs_resident(store)
+    assert len(store._resident) < n_before
+
+
+def test_an_upload_drops_a_run_held_outside_the_store():
+    """A run the store merged away while its caller still holds the
+    array leaves the device at the next upload."""
+    rng = np.random.default_rng(23)
+    store = LSMStore(0.5, value_words=2, kernel_impl="interpret")
+    for _ in range(12):
+        keys = rng.integers(0, 2_000, 40).astype(np.int64)
+        store.put_batch(keys, np.ones((40, 2), np.int32))
+    _probe_every_run(store)
+    held = store.levels[0][0]
+    _install(store)
+    assert all(run is not held for run in _live_runs(store))
+    assert held in store._resident          # freed only at the next upload
+    _probe_every_run(store)
+    assert held not in store._resident
+    assert_only_live_runs_resident(store)
+
+
+def test_a_dropped_store_frees_its_resident_keys():
+    """Run keys held outside the store (an installed snapshot) keep
+    their host array, but not the dropped store's device words."""
+    import gc
+    import weakref
+    keys = np.arange(0, 30_000, 3, dtype=np.int64)
+    store = LSMStore(0.5, value_words=2, kernel_impl="interpret")
+    store.install_run(keys, np.zeros((len(keys), 2), np.int32))
+    store._probe_run(keys, keys[:10])
+    device = weakref.ref(store._resident._by_id[id(keys)][1])
+    del store
+    gc.collect()
+    assert device() is None
+
+
+@pytest.mark.parametrize("tier", ["level", "memtable_run"])
+def test_writing_into_an_uploaded_run_raises(tier):
+    store = LSMStore(0.5, value_words=2, kernel_impl="interpret")
+    store.install_run(np.arange(100, dtype=np.int64),
+                      np.zeros((100, 2), np.int32))
+    store.put_batch(np.arange(5, dtype=np.int64) * 7,
+                    np.ones((5, 2), np.int32))
+    store.get_batch(np.arange(300, 310, dtype=np.int64))  # probes both
+    run = store.levels[0][0] if tier == "level" else store._runs[0][0]
+    assert run in store._resident
+    with pytest.raises(ValueError, match="read-only"):
+        run[0] = 1

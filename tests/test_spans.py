@@ -4,7 +4,8 @@
   mode) and of a small engine, every span in ``SPANS`` appears, and the
   kernel's spans nest inside the store's.
 * The counters equal the bytes and compares computed from the ``bucket``
-  ladder, whether spans are on or off.
+  ladder, whether spans are on or off; a table already on the device
+  sends only the queries.
 * Off, ``span`` hands out one shared null context, and the numpy store
   path never imports ``jax``.
 * The golden episodes decide byte-identically with spans on.
@@ -21,7 +22,7 @@ import pytest
 
 from repro.kernels.device import bucket
 from repro.kernels.sorted_probe.kernel import QUERY_BLOCK, TABLE_TILE
-from repro.kernels.sorted_probe.ops import probe
+from repro.kernels.sorted_probe.ops import probe, upload
 from repro.kernels.window_agg.kernel import EVENT_TILE, SEG_BLOCK
 from repro.kernels.window_agg.ops import aggregate
 from repro.obs import spans
@@ -168,6 +169,9 @@ def test_traced_counters_count_every_kernel_call(traced):
         assert counted[f"{kernel}.calls"] == len(
             _named(events, f"{kernel}.launch")), kernel
         assert counted[f"{kernel}.h2d_bytes"] > 0, kernel
+    # every probe call either uploads its table or finds it resident
+    assert counted["sorted_probe.table_uploads"] \
+        + counted["sorted_probe.table_reuses"] == counted["sorted_probe.calls"]
 
 
 @pytest.mark.parametrize("on", [False, True])
@@ -182,19 +186,30 @@ def test_counters_equal_the_bucketed_bytes_and_cells(on):
     spans.enable(on)
     try:
         before = spans.counts.copy()
-        probe(table, queries, impl="interpret")
+        resident = upload(table)
+        probe(resident, queries, impl="interpret")
         aggregate(seg, vals, s, impl="interpret")
         got = spans.counts - before
+        before = spans.counts.copy()
+        probe(resident, queries, impl="interpret")
+        again = spans.counts - before
     finally:
         spans.enable(False)
     tp, qp = bucket(t, TABLE_TILE), bucket(n, QUERY_BLOCK)
     eb, sb = bucket(e, EVENT_TILE), bucket(s, SEG_BLOCK)
     assert dict(got) == {
         "sorted_probe.calls": 1,
+        "sorted_probe.table_uploads": 1,
         "sorted_probe.h2d_bytes": 4 * 2 * (tp + qp),
         "window_agg.calls": 1,
         "window_agg.h2d_bytes": 4 * eb + 4 * v * eb,
         "window_agg.remapped": 1,           # random ids: sorted first
+    }
+    # the table stayed on the device: the second call sends the queries
+    assert dict(again) == {
+        "sorted_probe.calls": 1,
+        "sorted_probe.table_reuses": 1,
+        "sorted_probe.h2d_bytes": 4 * 2 * qp,
     }
 
 
