@@ -201,93 +201,6 @@ def bench_fleet() -> None:
         f.write("\n")
 
 
-def bench_lsm_store() -> None:
-    """Columnar vs legacy state backend on the PR 1 headline episode
-    (q8, justin policy, seed 3) — an in-process A/B: ``set_store_impl``
-    swaps every TaskRunner's store class, and the engine routes the
-    legacy store through the frozen pre-columnar partition installer so
-    it runs in its historical configuration.  min-of-N wall clock per
-    impl; writes ``BENCH_lsm.json`` (schema + regression gate in
-    tools/check_bench.py).
-
-    Scale: ``run.py lsm [repeats]`` (default 3); the suite-wide run (no
-    selector) uses the same default — one episode is seconds, not
-    minutes."""
-    import json
-    import os
-    import subprocess
-
-    argv = sys.argv[1:]
-    repeats = int(argv[1]) if argv and argv[0] == "lsm" and len(argv) > 1 \
-        else 3
-    query, seed = "q8", 3
-
-    snippet = """
-import json
-from repro.core.controller import AutoScaler, ControllerConfig
-from repro.core.justin import JustinParams
-from repro.data.nexmark import QUERIES, TARGET_RATES
-from repro.obs import MetricsRegistry
-from repro.state import lsm
-from repro.streaming.engine import StreamEngine
-lsm.set_store_impl({impl!r})
-flow = QUERIES[{query!r}]()
-eng = StreamEngine(flow, seed={seed})
-ctl = AutoScaler(eng, TARGET_RATES[{query!r}], ControllerConfig(
-    policy="justin", justin=JustinParams(max_level=2)))
-reg = MetricsRegistry()
-with reg.timer("episode") as tm:
-    ctl.run()
-s = ctl.summary()
-print(json.dumps({{"seconds": tm.s, "steps": s["steps"],
-                   "achieved_rate": s["achieved_rate"]}}))
-"""
-    src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
-    env = dict(os.environ,
-               PYTHONPATH=os.pathsep.join(
-                   p for p in (src, os.environ.get("PYTHONPATH")) if p))
-
-    def episode(impl: str) -> tuple[float, dict]:
-        out = subprocess.run(
-            [sys.executable, "-c",
-             snippet.format(impl=impl, query=query, seed=seed)],
-            capture_output=True, text=True, check=True, env=env)
-        r = json.loads(out.stdout.strip().splitlines()[-1])
-        return r["seconds"], r
-
-    secs: dict[str, list] = {"legacy": [], "columnar": []}
-    summs: dict[str, dict] = {}
-    # one fresh process per episode (allocator state from a previous
-    # episode measurably slows later ones), interleaved so drifting host
-    # load hits both sides of the A/B equally; min-of-N then discards
-    # the loaded episodes
-    for _ in range(repeats):
-        for impl in ("legacy", "columnar"):
-            dt, summs[impl] = episode(impl)
-            secs[impl].append(round(dt, 3))
-    runs = []
-    for impl in ("legacy", "columnar"):
-        summ = summs[impl]
-        runs.append({
-            "impl": impl, "query": query, "policy": "justin",
-            "seed": seed, "repeats": repeats, "seconds": secs[impl],
-            "seconds_min": min(secs[impl]), "steps": int(summ["steps"]),
-            "achieved_rate": float(summ["achieved_rate"]),
-        })
-        _row(f"lsm_{impl}_{query}", min(secs[impl]) * 1e6,
-             f"min_of={repeats};steps={summ['steps']};"
-             f"rate={summ['achieved_rate']:.0f}")
-    by = {r["impl"]: r["seconds_min"] for r in runs}
-    speedup = by["legacy"] / by["columnar"]
-    _row(f"lsm_speedup_{query}", 0.0, f"speedup={speedup:.2f}")
-    path = os.path.join(os.path.dirname(__file__), os.pardir,
-                        "BENCH_lsm.json")
-    with open(path, "w") as f:
-        json.dump({"bench": "lsm_store", "schema_version": 1,
-                   "speedup": round(speedup, 3), "runs": runs}, f, indent=2)
-        f.write("\n")
-
-
 def bench_justinserve() -> None:
     """Beyond-paper: hybrid LLM-serving elasticity."""
     from benchmarks.justinserve_bench import evaluate

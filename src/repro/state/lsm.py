@@ -41,8 +41,8 @@ int32 words per entry, so cache-capacity ratios match the paper exactly
 at 1/64th the RAM.
 
 Decision-identity invariants (pinned by ``tests/test_engine_fastpath.py``,
-``tests/test_lsm_differential.py`` against the frozen
-``repro.state.legacy.LegacyLSMStore``, and the golden traces):
+``tests/test_lsm_differential.py`` against a dict model and a recorded
+per-step digest of the observable state, and the golden traces):
 
 * reads see newest-write-wins values, identical to the old maintained
   view (runs are probed newest-first);
@@ -1114,32 +1114,3 @@ class LSMStore:
                             else np.asarray(w, np.int64),
                             np.asarray(snap["vals"], np.int32))
         return store
-
-
-# ------------------------------------------------------------- store factory
-# The engine/operators build state through here so benchmarks and the
-# differential harness can swap the frozen pre-columnar store
-# (repro.state.legacy) in-process and compare like for like.
-_ACTIVE_STORE_IMPL = "columnar"
-
-
-def set_store_impl(name: str) -> None:
-    global _ACTIVE_STORE_IMPL
-    if name not in ("columnar", "legacy"):
-        raise ValueError(f"unknown store impl {name!r}")
-    _ACTIVE_STORE_IMPL = name
-
-
-def get_store_impl() -> str:
-    return _ACTIVE_STORE_IMPL
-
-
-def store_class(name: str | None = None):
-    if (name or _ACTIVE_STORE_IMPL) == "columnar":
-        return LSMStore
-    from repro.state.legacy import LegacyLSMStore
-    return LegacyLSMStore
-
-
-def make_store(memory_mb: float, **kw) -> "LSMStore":
-    return store_class()(memory_mb, **kw)

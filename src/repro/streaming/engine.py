@@ -3,9 +3,8 @@
 Events are *really processed* (real LSM state, real vectorized operator
 compute); only wall-clock is modeled: each task has a per-tick time budget
 and each processed chunk charges ``events x cpu_cost + measured state
-latency`` against it (DESIGN.md §3 — this container has neither a TPU nor
-the paper's SSD testbed, so capacity comes from the calibrated service-time
-model over real executed work).
+latency`` against it: capacity comes from the calibrated service-time
+model over real executed work, not from the paper's SSD testbed.
 
 Mechanics faithful to Flink/the paper:
   * hash partitioning of keyed streams onto an operator's tasks,
@@ -84,6 +83,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from repro.obs.spans import span, step
+from repro.state.lsm import stable_argsort_keys
 from repro.streaming.events import EventBatch, hash_partition
 from repro.streaming.graph import Dataflow
 from repro.streaming.operators import (WINDOW_BITS, JoinOp, Operator,
@@ -228,22 +228,18 @@ class StreamEngine:
     def _install_partitions(self, name: str, sources: list[dict]) -> None:
         """Distribute state snapshots onto the op's tasks.
 
-        Replaces the old global ``np.lexsort((keys, part))`` with per-source
-        work that exploits what snapshots guarantee: keys are already
-        sorted.  Per source, one stable sort by destination partition keeps
-        each destination slice key-sorted; per destination, the per-source
-        slices are sorted runs merged by a single stable argsort over their
-        concatenation (ties resolve in source order — exactly the order the
-        global lexsort produced, duplicates across sources included).  Each
-        task gets its merged partition as one installed run plus a cache
-        prewarm over the partition in original arrival order (per-source
-        ascending positions, sources in order — the order the lexsort-slice
-        path fed the sampler, so the shared rng draws identically)."""
+        Equivalent to one global stable sort of the concatenated sources
+        by (partition, key), done per source instead, exploiting what
+        snapshots guarantee: keys are already sorted.  Per source, one
+        stable sort by destination partition keeps each destination slice
+        key-sorted; per destination, the per-source slices are sorted runs
+        merged by a single stable argsort over their concatenation (ties
+        resolve in source order, duplicates across sources included).
+        Each task gets its merged partition as one installed run plus a
+        cache prewarm over the partition in original arrival order
+        (per-source ascending positions, sources in order), drawing from
+        the shared rng task by task."""
         with span("engine.install"):
-            from repro.state.lsm import get_store_impl, stable_argsort_keys
-            if get_store_impl() == "legacy":
-                self._install_partitions_lexsort(name, sources)
-                return
             node = self.flow.nodes[name]
             p = len(self.tasks[name])
             assert p <= (1 << 16)    # partition ids must survive the uint16
@@ -298,30 +294,6 @@ class StreamEngine:
                     wv = av[i][0] if len(av[i]) == 1 else np.concatenate(av[i])
                     tr.state.prewarm_cache(wk, wv, self.rng)
                 tr.state.metrics.reset()
-
-    def _install_partitions_lexsort(self, name: str,
-                                    sources: list[dict]) -> None:
-        """The pre-columnar installer (one global ``np.lexsort``), kept
-        verbatim so the frozen legacy store runs in its own historical
-        configuration — ``benchmarks/run.py lsm`` A/Bs the two backends
-        like for like (store + install path together)."""
-        node = self.flow.nodes[name]
-        keys = np.concatenate([np.asarray(s["keys"], np.int64)
-                               for s in sources])
-        vals = np.concatenate([np.asarray(s["vals"], np.int32)
-                               for s in sources])
-        p = len(self.tasks[name])
-        part = hash_partition(state_partition_keys(node.op, keys), p)
-        srt = np.lexsort((keys, part))           # by partition, then key
-        bounds = np.searchsorted(part[srt], np.arange(p + 1))
-        for i in range(p):
-            tr = self.tasks[name][i]
-            run = srt[bounds[i]:bounds[i + 1]]
-            if len(run):
-                tr.state.install_run(keys[run], vals[run])
-                sl = np.sort(run)                # original order
-                tr.state.prewarm_cache(keys[sl], vals[sl], self.rng)
-            tr.state.metrics.reset()
 
     # ------------------------------------------------------------ snapshots
     def snapshot(self) -> dict:

@@ -17,7 +17,7 @@ from typing import Callable
 import numpy as np
 
 from repro.obs.spans import counts, span
-from repro.state.lsm import LSMStore, LatencyModel, make_store
+from repro.state.lsm import LSMStore, LatencyModel
 from repro.streaming.events import EventBatch, PAYLOAD_WORDS
 
 
@@ -34,10 +34,8 @@ class Operator:
     def make_state(self, memory_mb: float, seed: int = 0) -> LSMStore | None:
         if not self.stateful:
             return None
-        # built through the store factory so benchmarks and the
-        # differential harness can swap implementations engine-wide
-        return make_store(memory_mb, value_words=PAYLOAD_WORDS,
-                          entry_bytes=self.entry_bytes, seed=seed)
+        return LSMStore(memory_mb, value_words=PAYLOAD_WORDS,
+                        entry_bytes=self.entry_bytes, seed=seed)
 
     def process(self, state: LSMStore | None, batch: EventBatch) -> EventBatch:
         raise NotImplementedError

@@ -3,14 +3,17 @@ processing path and the optimized LSM internals must preserve determinism,
 snapshot/restore and reconfigure semantics, and stay bit-identical to the
 reference (sequential) CLOCK cache.
 """
+import copy
+
 import numpy as np
 import pytest
 
 from repro.data.nexmark import BidGen
 from repro.state.lsm import LSMStore
-from repro.streaming.engine import StreamEngine
+from repro.streaming.engine import StreamEngine, state_partition_keys
+from repro.streaming.events import PAYLOAD_WORDS, hash_partition
 from repro.streaming.graph import Dataflow
-from repro.streaming.operators import KeyedStateOp, SinkOp, SourceOp
+from repro.streaming.operators import JoinOp, KeyedStateOp, SinkOp, SourceOp
 
 
 def pressured_flow(p=1, keyspace=50_000):
@@ -81,6 +84,89 @@ def test_reconfigure_preserves_state_contents():
     assert len(eng.tasks["agg"]) == 5
     eng.run(2, 40_000)                       # still processes
     assert eng.collect()["sink"]["rate_in"] > 0
+
+
+# ------------------------------------------------ partition installer
+def _snapshot_sources(rng, kind, n_sources):
+    """State snapshots to re-partition: each source key-sorted and unique
+    (what ``LSMStore.snapshot`` returns), all drawn from one key pool so
+    keys repeat across sources.  Join keys carry the side and a tumbling
+    window id in their low bits, as ``JoinOp._skey`` builds them."""
+    sources = []
+    for _ in range(n_sources):
+        n = 24_000 // n_sources
+        if kind == "join":
+            ek = rng.integers(0, 6_000, n).astype(np.int64)
+            keys = (ek * 4 + rng.integers(0, 2, n)) * np.int64(1 << 16) \
+                + rng.integers(0, 3, n)
+        else:
+            keys = rng.integers(0, 40_000, n).astype(np.int64)
+        keys = np.unique(keys)
+        m = len(keys)
+        sources.append({
+            "keys": keys,
+            "vals": rng.integers(0, 2**31 - 1, (m, PAYLOAD_WORDS),
+                                 dtype=np.int64).astype(np.int32),
+            "weights": rng.integers(1, 4, m).astype(np.int64)})
+    return sources
+
+
+def reference_install(op, sources, memory_mb, rng):
+    """The plain installer: one global stable sort by (partition, key) of
+    the concatenated sources, in source order, carrying the weights; each
+    task's run goes into a fresh store, whose cache is then prewarmed in
+    arrival order, tasks in order, from the shared rng."""
+    keys = np.concatenate([s["keys"] for s in sources])
+    vals = np.concatenate([s["vals"] for s in sources])
+    weights = np.concatenate([s["weights"] for s in sources])
+    part = hash_partition(state_partition_keys(op, keys), len(memory_mb))
+    order = np.lexsort((keys, part))
+    stores = []
+    for i, mb in enumerate(memory_mb):
+        st = LSMStore(mb, value_words=PAYLOAD_WORDS,
+                      entry_bytes=op.entry_bytes, seed=i)
+        run = order[part[order] == i]
+        if len(run):
+            st.install_run(keys[run], vals[run], weights[run])
+            arrival = np.sort(run)
+            st.prewarm_cache(keys[arrival], vals[arrival], rng)
+        st.metrics.reset()
+        stores.append(st)
+    return stores
+
+
+@pytest.mark.parametrize("kind,p", [("join", 3), ("join", 9),
+                                    ("keyed", 1), ("keyed", 3)])
+def test_install_partitions_matches_plain_reference(kind, p):
+    """``StreamEngine._install_partitions`` (the rescale and warm-up
+    path) equals the plain reference: every task's run contents and
+    weights, its CLOCK cache, and the shared rng's position after the
+    prewarm draws."""
+    for n_sources in (1, 3):
+        op = (JoinOp("state", 0, 1, window_s=10.0) if kind == "join"
+              else KeyedStateOp("state", "update", prepopulate=False))
+        flow = Dataflow("install")
+        flow.chain(SourceOp("source", BidGen(seed=1)), op, SinkOp("sink"))
+        flow.nodes["state"].parallelism = p
+        eng = StreamEngine(flow, seed=5, base_mem_mb=1.0, warm=False)
+        sources = _snapshot_sources(np.random.default_rng(p * 10 + n_sources),
+                                    kind, n_sources)
+        rng = copy.deepcopy(eng.rng)
+        eng._install_partitions("state", sources)
+        want = reference_install(
+            op, sources, [t.state.memory_mb for t in eng.tasks["state"]], rng)
+        assert eng.rng.bit_generator.state == rng.bit_generator.state
+        for i, (t, ref) in enumerate(zip(eng.tasks["state"], want)):
+            tag = f"{kind} p={p} sources={n_sources} task={i}"
+            got, exp = t.state.snapshot(), ref.snapshot()
+            for field in ("keys", "vals", "weights"):
+                np.testing.assert_array_equal(got[field], exp[field],
+                                              err_msg=f"{tag} {field}")
+            for attr in ("cache_keys", "cache_vals", "cache_ref",
+                         "cache_hand"):
+                np.testing.assert_array_equal(getattr(t.state, attr),
+                                              getattr(ref, attr),
+                                              err_msg=f"{tag} {attr}")
 
 
 # ---------------------------------------------------- LSM micro-invariants

@@ -1,29 +1,42 @@
-"""Differential store-testing harness: columnar vs legacy vs dict model.
+"""Differential store-testing harness: ``LSMStore`` vs a dict model and a
+recorded trajectory.
 
 Random op sequences (put_batch / get_batch / items / resize / snapshot /
-bulk_load) drive the columnar ``LSMStore`` and the frozen pre-columnar
-``LegacyLSMStore`` in lockstep, asserting identical *observable* state
-after every op:
+bulk_load) drive the columnar ``LSMStore`` and check after every op:
 
-* get_batch values + found masks (and both must match a python-dict
-  model with newest-write-wins semantics);
-* the full metrics snapshot — every θ/τ input the policies read;
-* bit-identical CLOCK cache arrays (keys/vals/ref/hand);
-* entry_count (the migration payload measure) and items();
-* resize-spill and snapshot/restore semantics pinned in PR 1/PR 4.
+* get_batch values + found masks and items() against a python-dict
+  model with newest-write-wins semantics;
+* the snapshot/restore round trip (the restored store holds exactly the
+  snapshot's keys and values);
+* a digest of the step's observable state against
+  ``tests/data/lsm_differential_digests.json``: the op's outputs, the
+  full metrics snapshot (every θ/τ input the policies read), entry_count
+  (the migration payload measure), the cache geometry and the
+  bit-identical CLOCK cache arrays (keys/vals/ref/hand).
+
+The record was taken while this harness still drove the pre-columnar
+store in lockstep and asserted identical observable state after every
+op, so it pins that store's trajectory step for step.  It is
+regenerated only under the rules for the golden traces
+(docs/golden-traces.md).
 
 This is the gate that makes ripping out store internals safe: any
 store-internal change must pass this harness BEFORE a golden-trace regen
-is even considered (see docs/golden-traces.md).
+is even considered.
 
 Sequences are generated from pinned numpy seeds so the suite needs no
 optional dependencies; when ``hypothesis`` is installed an extra
-property-driven case searches the same op space adversarially.
+property-driven case searches the same op space adversarially against
+the dict model.
 """
+import functools
+import hashlib
+import json
+from pathlib import Path
+
 import numpy as np
 import pytest
 
-from repro.state.legacy import LegacyLSMStore
 from repro.state.lsm import LSMStore
 
 try:
@@ -32,9 +45,10 @@ try:
 except ImportError:
     HAS_HYPOTHESIS = False
 
-N_SEQUENCES = 220                    # acceptance floor is 200 per pair
+N_SEQUENCES = 220                    # acceptance floor is 200
 KEYSPACE = 4_000
 CACHE_ATTRS = ("cache_keys", "cache_vals", "cache_ref", "cache_hand")
+RECORD_PATH = Path(__file__).parent / "data" / "lsm_differential_digests.json"
 
 
 def _gen_sequence(seed: int):
@@ -71,20 +85,34 @@ def _gen_sequence(seed: int):
     return memory_mb, use_filter, ops
 
 
-def _assert_state_equal(a: LSMStore, b: LegacyLSMStore, tag: str) -> None:
-    assert a.metrics.snapshot() == b.metrics.snapshot(), tag
-    assert a.entry_count == b.entry_count, tag
-    assert a.memtable_cap == b.memtable_cap, tag
-    assert (a.cache_sets, a.cache_ways) == (b.cache_sets, b.cache_ways), tag
+def _digest(store: LSMStore, outputs: tuple) -> str:
+    """16-hex sha256 prefix of one step: the op's outputs, then the
+    store's observable state.  Arrays fold in with dtype and shape."""
+    h = hashlib.sha256()
+
+    def fold(x) -> None:
+        if isinstance(x, np.ndarray):
+            a = np.ascontiguousarray(x)
+            h.update(f"{a.dtype}{a.shape}".encode())
+            h.update(a.tobytes())
+        else:
+            h.update(repr(x).encode())
+
+    for x in outputs:
+        fold(x)
+    fold([(k, float(v)) for k, v in sorted(store.metrics.snapshot().items())])
+    fold((store.entry_count, store.memtable_cap,
+          store.cache_sets, store.cache_ways))
     for attr in CACHE_ATTRS:
-        np.testing.assert_array_equal(getattr(a, attr), getattr(b, attr),
-                                      err_msg=tag)
+        fold(getattr(store, attr))
+    return h.hexdigest()[:16]
 
 
-def _run_sequence(seed: int) -> None:
+def _run_sequence(seed: int) -> list[str]:
+    """Drive one sequence, checking the dict model after every op;
+    return the per-step digests."""
     memory_mb, use_filter, ops = _gen_sequence(seed)
-    col = LSMStore(memory_mb, value_words=2)
-    leg = LegacyLSMStore(memory_mb, value_words=2)
+    store = LSMStore(memory_mb, value_words=2)
     model: dict[int, tuple] = {}
     # bulk_load is a pre-population fast path: it installs its run BELOW
     # the live memtable and never touches the cache.  Two consequences the
@@ -102,20 +130,20 @@ def _run_sequence(seed: int) -> None:
     tainted: set[int] = set()
     mem_count = 0
     if use_filter:
-        keep = lambda keys: keys % 3 != 0           # annihilate a third
-        col.compact_filter = keep
-        leg.compact_filter = keep
+        # annihilate a third
+        store.compact_filter = lambda keys: keys % 3 != 0
 
+    digests = []
     for step, op in enumerate(ops):
         tag = f"seed={seed} step={step} op={op[0]}"
+        outputs: tuple = ()
         if op[0] == "put":
             _, keys, vals = op
-            col.put_batch(keys, vals)
-            leg.put_batch(keys, vals)
+            store.put_batch(keys, vals)
             for k, v in zip(keys.tolist(), vals.tolist()):
                 model[k] = tuple(v)
             tainted.difference_update(keys.tolist())
-            off, cap = 0, col.memtable_cap
+            off, cap = 0, store.memtable_cap
             while off < len(keys):
                 take = min(cap - mem_count, len(keys) - off)
                 epoch_puts.update(keys[off:off + take].tolist())
@@ -126,26 +154,22 @@ def _run_sequence(seed: int) -> None:
                     mem_count = 0
         elif op[0] == "get":
             _, q = op
-            gc, fc = col.get_batch(q)
-            gl, fl = leg.get_batch(q)
-            np.testing.assert_array_equal(fc, fl, err_msg=tag)
-            np.testing.assert_array_equal(gc, gl, err_msg=tag)
+            got, found = store.get_batch(q)
+            outputs = (got, found)
             if not use_filter:          # the dict model has no annihilation
                 for i, k in enumerate(q.tolist()):
-                    assert bool(fc[i]) == (k in model), tag
-                    if fc[i] and k not in tainted:
-                        assert tuple(gc[i].tolist()) == model[k], tag
+                    assert bool(found[i]) == (k in model), tag
+                    if found[i] and k not in tainted:
+                        assert tuple(got[i].tolist()) == model[k], tag
         elif op[0] == "resize":
-            col.resize(op[1])
-            leg.resize(op[1])
+            store.resize(op[1])
             epoch_puts.clear()           # resize spills the memtable
             tainted.clear()              # ...and rebuilds an empty cache
             mem_count = 0
         elif op[0] == "bulk":
             _, keys, vals = op
-            col.bulk_load(keys, vals)
-            leg.bulk_load(keys, vals)
-            cached = set(col.cache_keys[col.cache_keys >= 0].tolist())
+            store.bulk_load(keys, vals)
+            cached = set(store.cache_keys[store.cache_keys >= 0].tolist())
             for k, v in zip(keys.tolist(), vals.tolist()):
                 if k in epoch_puts:      # memtable puts shadow bulk runs
                     continue
@@ -153,34 +177,51 @@ def _run_sequence(seed: int) -> None:
                 if k in cached:          # stale cached copy may serve first
                     tainted.add(k)
         elif op[0] == "items":
-            kc, vc = col.items()
-            kl, vl = leg.items()
-            np.testing.assert_array_equal(kc, kl, err_msg=tag)
-            np.testing.assert_array_equal(vc, vl, err_msg=tag)
+            keys, vals = store.items()
+            outputs = (keys, vals)
             if not use_filter:
-                assert set(kc.tolist()) == set(model), tag
+                assert set(keys.tolist()) == set(model), tag
         elif op[0] == "snapshot":
-            sc = col.snapshot()
-            sl = leg.snapshot()
-            np.testing.assert_array_equal(sc["keys"], sl["keys"],
-                                          err_msg=tag)
-            np.testing.assert_array_equal(sc["vals"], sl["vals"],
-                                          err_msg=tag)
-            assert sc["memory_mb"] == sl["memory_mb"], tag
-            # weights are columnar-only; each occurrence counted once
-            assert int(sc["weights"].sum()) >= len(sc["keys"]), tag
-            rc = LSMStore.restore(sc)
-            rl = LegacyLSMStore.restore(sl)
-            np.testing.assert_array_equal(rc.items()[0], rl.items()[0],
-                                          err_msg=tag)
-            np.testing.assert_array_equal(rc.items()[1], rl.items()[1],
-                                          err_msg=tag)
-        _assert_state_equal(col, leg, tag)
+            snap = store.snapshot()
+            # each occurrence counted once
+            assert int(snap["weights"].sum()) >= len(snap["keys"]), tag
+            rk, rv = LSMStore.restore(snap).items()
+            np.testing.assert_array_equal(rk, snap["keys"], err_msg=tag)
+            np.testing.assert_array_equal(rv, snap["vals"], err_msg=tag)
+            outputs = (snap["keys"], snap["vals"], snap["weights"],
+                       snap["memory_mb"], rk, rv)
+        digests.append(_digest(store, outputs))
+    return digests
+
+
+@functools.cache
+def _load_record() -> dict[str, list[str]]:
+    return json.loads(RECORD_PATH.read_text())
 
 
 @pytest.mark.parametrize("seed", range(N_SEQUENCES))
-def test_columnar_matches_legacy_and_model(seed):
-    _run_sequence(seed)
+def test_store_matches_model_and_record(seed):
+    got = _run_sequence(seed)
+    want = _load_record()[str(seed)]
+    assert len(got) == len(want), (seed, len(got), len(want))
+    first = next((i for i, (g, w) in enumerate(zip(got, want)) if g != w),
+                 None)
+    if first is not None:
+        op = _gen_sequence(seed)[2][first][0]
+        pytest.fail(f"seed={seed}: step {first} ({op}) is the first to "
+                    f"differ from the record: {got[first]} != {want[first]}")
+
+
+def test_record_covers_every_pinned_sequence():
+    """The record holds exactly the pinned seeds, one digest per op of
+    each generated sequence, so it cannot shrink or drift from the
+    generator unnoticed."""
+    record = _load_record()
+    assert sorted(record, key=int) == [str(s) for s in range(N_SEQUENCES)]
+    for seed in range(N_SEQUENCES):
+        digests = record[str(seed)]
+        assert len(digests) == len(_gen_sequence(seed)[2]), seed
+        assert all(len(d) == 16 and int(d, 16) >= 0 for d in digests), seed
 
 
 def test_sequence_space_covers_all_ops():
@@ -221,7 +262,7 @@ def test_get_batch_uhint_identical():
 
 
 def test_weight_semantics_columnar():
-    """Z-set bookkeeping the legacy store can't express: weights count
+    """Z-set bookkeeping: weights count
     write occurrences, survive snapshot/restore, and annihilated weight
     is tracked when the compaction filter drops keys."""
     s = LSMStore(0.25, value_words=2)
@@ -245,6 +286,7 @@ def test_weight_semantics_columnar():
 if HAS_HYPOTHESIS:
     @settings(max_examples=30, deadline=None)
     @given(st.integers(10_000, 10_000_000))
-    def test_columnar_matches_legacy_hypothesis(seed):
-        """Adversarial search over the same sequence space (extra seeds)."""
+    def test_store_matches_model_hypothesis(seed):
+        """Adversarial search over the same sequence space (extra,
+        unrecorded seeds) against the dict model and the round trip."""
         _run_sequence(seed)

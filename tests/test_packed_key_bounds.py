@@ -17,7 +17,7 @@ aliasing fixes that A701 (the escape pass) surfaced:
 import numpy as np
 import pytest
 
-from repro.state.lsm import LSMStore, make_store
+from repro.state.lsm import LSMStore
 
 LIM45 = np.int64(1) << np.int64(45)
 
@@ -29,7 +29,7 @@ def _vals(keys, words=4):
 
 
 def _store(**kw):
-    return make_store(64, **kw)
+    return LSMStore(64, **kw)
 
 
 # ------------------------------------------------- band-collision regression

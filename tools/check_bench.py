@@ -1,21 +1,15 @@
 #!/usr/bin/env python
-"""Schema + regression check for committed bench artifacts.
+"""Schema check for committed bench artifacts.
 
 Dispatches on the artifact's ``bench`` tag:
 
 * ``cluster_fleet`` — BENCH_cluster.json, the fleet-driver bench
-* ``lsm_store``     — BENCH_lsm.json, the legacy-vs-columnar store A/B
 
 CI runs the bench and then this checker; any drift in the emitted
 schema — renamed keys, wrong types, impossible counts — fails the build
 instead of silently producing an unplottable artifact.
 
-With ``--baseline PATH`` the headline metric is also compared against a
-committed reference artifact of the same bench kind, and the check fails
-on a regression of more than REGRESSION_TOLERANCE (20%) — the gate that
-keeps the columnar store's speedup from silently rotting.
-
-    python tools/check_bench.py [ARTIFACT.json] [--baseline PATH]
+    python tools/check_bench.py [ARTIFACT.json]
 """
 from __future__ import annotations
 
@@ -24,7 +18,6 @@ import json
 import sys
 
 SCHEMA_VERSION = 1
-REGRESSION_TOLERANCE = 0.20          # fail if headline drops >20%
 
 # key -> required type(s); bool is an int subclass, so exclude it where
 # a genuine number is meant
@@ -46,19 +39,6 @@ FLEET_RUN_KEYS = {
     "driver": str,
     "seed": int,
 }
-
-LSM_RUN_KEYS = {
-    "impl": str,
-    "query": str,
-    "policy": str,
-    "seed": int,
-    "repeats": int,
-    "seconds": list,
-    "seconds_min": (int, float),
-    "steps": int,
-    "achieved_rate": (int, float),
-}
-
 
 def _check_run_keys(run: dict, i: int, schema: dict) -> list[str]:
     errors = []
@@ -112,53 +92,14 @@ def check_cluster_fleet(data) -> list[str]:
     return errors
 
 
-def check_lsm_store(data) -> list[str]:
-    errors, runs = _check_common(data)
-    if not isinstance(data.get("speedup"), (int, float)) \
-            or isinstance(data.get("speedup"), bool):
-        errors.append(f"speedup is not a number: {data.get('speedup')!r}")
-    mins: dict[str, float] = {}
-    for i, run in enumerate(runs):
-        if not isinstance(run, dict):
-            errors.append(f"runs[{i}] is not an object")
-            continue
-        key_errors = _check_run_keys(run, i, LSM_RUN_KEYS)
-        if key_errors:
-            errors += key_errors
-            continue
-        secs = run["seconds"]
-        if len(secs) != run["repeats"] or not all(
-                isinstance(s, (int, float)) and not isinstance(s, bool)
-                and s > 0 for s in secs):
-            errors.append(f"runs[{i}]: seconds is not {run['repeats']} "
-                          "positive numbers")
-            continue
-        if abs(run["seconds_min"] - min(secs)) > 1e-9:
-            errors.append(f"runs[{i}]: seconds_min != min(seconds)")
-        if run["achieved_rate"] <= 0:
-            errors.append(f"runs[{i}]: non-positive achieved_rate")
-        mins[run["impl"]] = run["seconds_min"]
-    if not errors:
-        if set(mins) != {"legacy", "columnar"}:
-            errors.append(f"impls != {{legacy, columnar}}: {sorted(mins)}")
-        else:
-            derived = mins["legacy"] / mins["columnar"]
-            if abs(derived - data["speedup"]) > 0.01:
-                errors.append(f"speedup {data['speedup']} is not "
-                              f"legacy_min/columnar_min ({derived:.3f})")
-    return errors
-
-
 CHECKERS = {
     "cluster_fleet": check_cluster_fleet,
-    "lsm_store": check_lsm_store,
 }
 
-# headline metric per bench kind: (extractor, higher_is_better)
+# headline metric per bench kind, printed on success
 HEADLINES = {
     "cluster_fleet": lambda d: max(r["tenant_windows_per_s"]
                                    for r in d["runs"]),
-    "lsm_store": lambda d: d["speedup"],
 }
 
 
@@ -173,22 +114,6 @@ def check(data) -> list[str]:
     return checker(data)
 
 
-def check_baseline(data, base) -> list[str]:
-    """Headline regression gate: fail when the current artifact's headline
-    metric (both benches: higher is better) drops more than
-    REGRESSION_TOLERANCE below the committed baseline's."""
-    if data.get("bench") != base.get("bench"):
-        return [f"baseline bench kind {base.get('bench')!r} does not match "
-                f"artifact {data.get('bench')!r}"]
-    extract = HEADLINES[data["bench"]]
-    cur, ref = extract(data), extract(base)
-    floor = ref * (1.0 - REGRESSION_TOLERANCE)
-    if cur < floor:
-        return [f"headline regression: {cur:.3f} < {floor:.3f} "
-                f"(baseline {ref:.3f} - {REGRESSION_TOLERANCE:.0%})"]
-    return []
-
-
 def _load(path: str):
     with open(path) as f:
         return json.load(f)
@@ -197,12 +122,6 @@ def _load(path: str):
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("artifact", nargs="?", default="BENCH_cluster.json")
-    ap.add_argument("--baseline", metavar="PATH",
-                    # %% — argparse %-interpolates help strings, so a bare
-                    # "20%" raises TypeError the moment --help renders
-                    help="committed reference artifact; fail on >"
-                         f"{REGRESSION_TOLERANCE * 100:.0f}%% headline "
-                         f"regression")
     args = ap.parse_args()
     try:
         data = _load(args.artifact)
@@ -210,19 +129,6 @@ def main() -> int:
         print(f"check_bench: cannot read {args.artifact}: {e}")
         return 1
     errors = check(data)
-    if not errors and args.baseline:
-        try:
-            base = _load(args.baseline)
-        except (OSError, json.JSONDecodeError) as e:
-            print(f"check_bench: cannot read baseline "
-                  f"{args.baseline}: {e}")
-            return 1
-        base_errors = check(base)
-        if base_errors:
-            errors += [f"baseline {args.baseline}: {e}"
-                       for e in base_errors]
-        else:
-            errors += check_baseline(data, base)
     for e in errors:
         print(f"check_bench: {args.artifact}: {e}")
     if not errors:

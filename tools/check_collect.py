@@ -16,7 +16,7 @@ import sys
 from collections import Counter
 
 # suite -> minimum collected tests.  The differential harness floor is
-# the PR acceptance criterion (>=200 random op sequences per store pair);
+# the PR acceptance criterion (>=200 recorded random op sequences);
 # the reprolint floor pins the 19-fixture parametrization (per-file
 # rules AND the PR 9 interprocedural passes) plus the baseline/CLI
 # contract and cross-file pass tests; the packed-key floor pins the

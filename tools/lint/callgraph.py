@@ -18,7 +18,7 @@ the passes inherit its precision):
   method, walking program-visible base classes; if the class doesn't
   define it anywhere visible, it falls back to the union of all methods
   named ``m`` (the U401-style whole-program convention).
-* **Module-alias chains** (``lsm.make_store(...)``, ``t.time(...)``)
+* **Module-alias chains** (``lsm.merge_delta_runs(...)``, ``t.time(...)``)
   expand through the import-alias table.  In-program targets become
   edges; the rest are recorded verbatim as *external chains* so sink
   predicates (``time.*``, ``numpy.random.*``) can match them even

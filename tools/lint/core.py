@@ -16,9 +16,8 @@ framework; the rule panel lives in :mod:`tools.lint.rules`:
   renumbering, so unrelated edits don't churn the committed baseline.
 * **Baseline** — a committed JSON multiset of finding keys
   (``tools/lint/baseline.json``) grandfathers findings that are real but
-  deliberately not fixed (e.g. the frozen ``state/legacy.py`` store, which
-  is the A/B baseline and must never be edited).  ``--fail-on-new`` fails
-  only on findings whose key is NOT in the baseline.
+  deliberately not fixed.  ``--fail-on-new`` fails only on findings
+  whose key is NOT in the baseline.
 * **Suppression** — ``# reprolint: ignore[D103]`` on the offending line
   silences that rule there (bare ``# reprolint: ignore`` silences all);
   suppressions are counted and reported so they can't hide silently.

@@ -1,17 +1,15 @@
 # as: src/repro/serve/registry_bad.py
 """Known-bad registry-discipline fixture: string dispatch on .policy,
-direct store construction, an unregistered policy, history patching."""
+an unregistered policy, history patching."""
 from repro.core.policy import ScalingPolicy
-from repro.state.lsm import LSMStore
 
 
-def build(cfg, capacity_mb):
+def build(cfg):
     if cfg.policy == "justin":                       # expect: R301
         mode = "hybrid"
     elif cfg.policy in ("ds2", "static"):            # expect: R301
         mode = "cpu-only"
-    store = LSMStore(capacity_mb)                    # expect: R302
-    return mode, store
+    return mode
 
 
 class ShadowPolicy(ScalingPolicy):                   # expect: R303

@@ -2,13 +2,11 @@
 """Known-good registry-discipline fixture: registry construction paths
 and read-only history access."""
 from repro.core.policy import ScalingPolicy, make_policy, register_policy
-from repro.state.lsm import make_store
 
 
-def build(cfg, capacity_mb):
+def build(cfg):
     policy = make_policy(cfg.policy)
-    store = make_store(capacity_mb)
-    return policy, store
+    return policy
 
 
 @register_policy("shadow")

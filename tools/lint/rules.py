@@ -16,9 +16,9 @@ Four families (docs/static-analysis.md has the user-facing table):
   that mutates them.
 * **R — registry discipline.**  Policies are constructed through
   ``@register_policy``/``make_policy`` (never ``cfg.policy`` string
-  dispatch), stores through ``make_store`` (never direct ``LSMStore``
-  construction), and ``HistoryRow``\\ s are immutable once appended except
-  in the two blessed driver modules.  Golden-trace-critical modules
+  dispatch), every ``ScalingPolicy`` subclass is registered, and
+  ``HistoryRow``\\ s are immutable once appended except in the two
+  blessed driver modules.  Golden-trace-critical modules
   import no nondeterminism sources at all.
 * **U — units.**  MB, CPU slots and seconds must not cross call
   boundaries: a parameter named ``*_mb`` fed an argument named ``*_s``
@@ -380,31 +380,6 @@ class PolicyStringDispatch(Rule):
                     "policy via make_policy(...) and dispatch on the "
                     "instance (isinstance / protocol hooks), or register "
                     "a policy subclass"))
-        return out
-
-
-@register_rule
-class DirectStoreConstruction(Rule):
-    """Direct LSMStore/LegacyLSMStore construction outside repro.state —
-    bypassing make_store breaks the A/B store-impl switch the
-    differential harness relies on."""
-    id = "R302"
-    title = "direct store construction bypassing make_store"
-    exempt = ("src/repro/state/",)
-
-    _STORES = {"LSMStore", "LegacyLSMStore"}
-
-    def visit(self, unit: FileUnit) -> list[Finding]:
-        out: list[Finding] = []
-        for node in ast.walk(unit.tree):
-            if isinstance(node, ast.Call):
-                t = terminal_name(node.func)
-                if t in self._STORES:
-                    out.append(unit.finding(
-                        self, node,
-                        f"direct {t}(...) construction — build stores via "
-                        f"repro.state.lsm.make_store so set_store_impl "
-                        f"(the legacy/columnar A/B switch) keeps working"))
         return out
 
 
